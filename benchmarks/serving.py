@@ -38,9 +38,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
 # mesh sweep: every candidate must factor the host devices exactly and
 # keep g_seq == 1 (serving is gated to non-seq-sharded meshes)
 MESHES = [("gdata2_gx2_gy2", (2, 2, 2, 1)),
@@ -228,7 +225,7 @@ def run_continuous(cfg, mesh, axes, params, reqs, args, mesh_name=""):
         telem = TL.Telemetry(
             f"serving-{mesh_name or 'mesh'}",
             flops_per_token=CM.model_flops_per_token(cfg, "serve"),
-            peak_flops_per_device=CM.TPU_V5E.flops,
+            peak_flops_per_device=TL.peak_flops_per_device(),
             n_devices=int(mesh.devices.size), verbose=False,
             meta={"arch": cfg.name, "mesh": mesh_name,
                   "slots": args.slots, "pages": args.pages,
@@ -345,6 +342,9 @@ def suite(calib: str = "", args=None) -> List[Tuple[str, float, str]]:
 
 
 def main() -> None:
+    # the mesh sweep needs 8 host devices on CPU; set before JAX starts
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     args = build_parser().parse_args()
     print("name,us_per_call,derived")
     for label, val, derived in suite(calib=args.calib, args=args):

@@ -330,10 +330,10 @@ def _collective_fns(mesh, axis):
     ``reduce_scatter``/``all_reduce``/``psum`` the rank's full-size
     partial."""
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import mesh as M
-    from repro.core.compat import shard_map
 
     def wrap(body, in_spec, out_spec):
         return jax.jit(shard_map(body, mesh=mesh, in_specs=(in_spec,),
@@ -444,10 +444,10 @@ def overlap_probe(mesh, axis: str, *, elems: int = 1 << 16,
     better; ``layer_time`` consults the verdict)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import mesh as M
-    from repro.core.compat import shard_map
 
     p = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis])
     if p <= 1:
@@ -487,10 +487,10 @@ def cross_step_probe(mesh, axis: str, *, elems: int = 1 << 16,
     ``cross_step`` window may actually claim."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import mesh as M
-    from repro.core.compat import shard_map
 
     p = int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis])
     if p <= 1:

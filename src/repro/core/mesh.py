@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import trace
-from repro.core.compat import axis_size as _axis_size
 from repro.core.overlap import OverlapConfig
 
 AxisName = Union[str, Tuple[str, ...], None]
@@ -253,7 +252,7 @@ def flat_ring_axis(axis: AxisName):
     :func:`all_gather` / :func:`psum_scatter` helpers produce the same
     layout, so ring and blocking schedules stay interchangeable."""
     n = _names(axis)
-    p = math.prod(_axis_size(name) for name in n)
+    p = math.prod(jax.lax.axis_size(name) for name in n)
     return p, (n if len(n) > 1 else n[0])
 
 
@@ -461,7 +460,7 @@ def axis_index(axis: AxisName):
         return jnp.int32(0)
     idx = jnp.int32(0)
     for name in n:
-        idx = idx * _axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

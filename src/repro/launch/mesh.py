@@ -27,12 +27,14 @@ import jax
 import numpy as np
 
 from repro.core import mesh as M
-from repro.core import compat as C
 
 
-def _mk(shape, names):
-    return C.make_mesh(shape, names,
-                       axis_types=C.default_axis_types(len(names)))
+def _mk(shape, names, devices=None):
+    # Auto axes: the step builders place every collective by hand inside
+    # shard_map, so no axis may be Explicit (jax.make_mesh's default)
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -223,15 +225,7 @@ class MeshLifecycle:
         if self.g_expert > 1:
             shape += (self.g_expert,)
             names += ("expert",)
-        if not self._failed and need == len(self.devices) \
-                and self._devices is not None:
-            # intact pool covering every device: the legacy factory path,
-            # so generation 0 is byte-identical to make_smoke_mesh
-            self.mesh = _mk(shape, names)
-        else:
-            self.mesh = C.make_mesh(
-                shape, names, axis_types=C.default_axis_types(len(names)),
-                devices=surv[:need])
+        self.mesh = _mk(shape, names, devices=surv[:need])
         self.axes = bind_4d(self.mesh)
         self.generation += 1
         self.state = "active"

@@ -40,11 +40,11 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
+from jax import shard_map
 import numpy as np
 
 from repro.core import comm_model as CM
 from repro.core import mesh as M
-from repro.core.compat import shard_map
 from repro.launch.telemetry import DriftMonitor
 
 PROBE_CLASSES = ("z_ring", "xy_ar", "seq_ring", "dp_rs_ag")

@@ -31,8 +31,8 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.core.overlap import OverlapConfig
-from repro.core.partition import spec_tree_to_pspecs
 from repro.launch import mesh as LM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import steps as ST
 
 
@@ -108,9 +108,8 @@ def _setup(args):
     if args.preset == "smoke":
         cfg = cfg.reduced()
     dtype = jnp.float32
-    params, specs = ST.init_model(cfg, axes, jax.random.PRNGKey(0),
-                                  dtype=dtype)
-    params = ST.device_put_tree(mesh, params, spec_tree_to_pspecs(specs))
+    params, _ = ST.init_sharded(cfg, mesh, axes, jax.random.PRNGKey(0),
+                                dtype=dtype)
     ov = (OverlapConfig.all_on(z_chunks=args.z_chunks,
                                ar_chunks=args.ar_chunks)
           if args.overlap else OverlapConfig())
@@ -205,7 +204,7 @@ def run_continuous(args) -> None:
             run_name, path=args.log_file,
             tokens_per_step=0,  # serve steps carry their own new_tokens
             flops_per_token=CM.model_flops_per_token(cfg, "serve"),
-            peak_flops_per_device=CM.TPU_V5E.flops,
+            peak_flops_per_device=TL.peak_flops_per_device(),
             n_devices=int(mesh.devices.size),
             meta={"arch": cfg.name, "mesh": args.mesh, "mode": "continuous",
                   "slots": args.slots, "pages": args.pages,
@@ -237,6 +236,7 @@ def run_continuous(args) -> None:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.mode == "fixed":
         run_fixed(args)
     else:

@@ -15,11 +15,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import gradsync as GS
 from repro.core import mesh as M
-from repro.core.compat import shard_map
 from repro.core import parallel as PP
 from repro.core.gradsync import GradSyncConfig
 from repro.core.overdecompose import split_batch
@@ -47,6 +47,21 @@ def init_model(cfg: ArchConfig, axes: M.MeshAxes, key=None, *,
         boxed = D.decoder_init(key, cfg, axes, dtype=dtype,
                                abstract=abstract)
     return unbox(boxed)
+
+
+def init_sharded(cfg: ArchConfig, mesh: Mesh, axes: M.MeshAxes, key, *,
+                 dtype=jnp.bfloat16):
+    """``init_model`` placed on ``mesh`` as it is computed: one jitted
+    program whose outputs carry the parameters' shardings, so each device
+    computes only its own shards and none holds the whole model (the
+    eager ``init_model`` materializes every leaf on one device first).
+    The values are those of ``init_model`` with the same key."""
+    structs, specs = init_model(cfg, axes, abstract=True, dtype=dtype)
+    shardings = jax.tree.map(lambda _, s: NamedSharding(mesh, s), structs,
+                             spec_tree_to_pspecs(specs))
+    params = jax.jit(lambda k: init_model(cfg, axes, k, dtype=dtype)[0],
+                     out_shardings=shardings)(key)
+    return params, specs
 
 
 # ---------------------------------------------------------------------- #

@@ -30,8 +30,8 @@ prints a human summary table at exit. Record kinds:
 **MFU** is ``model_flops_per_token(cfg) * tokens/s`` over the mesh's
 aggregate peak FLOP/s — *model* flops (``6 * N_active`` per trained
 token), not HLO flops, so remat recompute does not inflate it; the peak
-is the calibration profile's measured GEMM throughput when ``--calib``
-is given (TPU-v5e paper constants otherwise). Step timing blocks on the
+is the device's published per-chip peak (:data:`DEVICE_PEAKS`). On the
+CPU there is no such peak and MFU is null. Step timing blocks on the
 step's metrics each iteration, so enabling telemetry serializes the
 host loop with the device — a per-step cost the async default never
 pays; the degenerate path (no ``--telemetry``) is unchanged.
@@ -116,17 +116,50 @@ def validate_file(path: str) -> int:
     return n
 
 
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """Published peak rates of one chip."""
+    flops: float   # dense bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. Source: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+DEVICE_PEAKS: Dict[str, DevicePeak] = {
+    "TPU v5 lite": DevicePeak(flops=197e12, hbm_bw=819e9),
+}
+
+
+def device_peak(device=None) -> Optional[DevicePeak]:
+    """The published peak of ``device`` (default: the first JAX device).
+
+    None on the CPU, which has no published peak, so no utilization is
+    reported there. An accelerator missing from :data:`DEVICE_PEAKS` is an
+    error: a utilization priced against another chip's peak is wrong."""
+    import jax
+    d = device if device is not None else jax.devices()[0]
+    if d.platform == "cpu":
+        return None
+    if d.device_kind not in DEVICE_PEAKS:
+        raise ValueError(f"no published peak for device kind "
+                         f"{d.device_kind!r} ({d.platform}); add it to "
+                         f"launch.telemetry.DEVICE_PEAKS")
+    return DEVICE_PEAKS[d.device_kind]
+
+
+def peak_flops_per_device() -> Optional[float]:
+    """MFU denominator per device: the table's bf16 peak, None on CPU."""
+    peak = device_peak()
+    return peak.flops if peak is not None else None
+
+
 def peak_memory_bytes() -> Optional[int]:
     """Max ``peak_bytes_in_use`` over local devices, or None when the
     backend keeps no memory stats (host CPU does not)."""
     import jax
     best = None
     for d in jax.local_devices():
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
-        v = (stats or {}).get("peak_bytes_in_use")
+        v = (d.memory_stats() or {}).get("peak_bytes_in_use")
         if v is not None:
             best = v if best is None else max(best, v)
     return best
@@ -225,21 +258,23 @@ class Telemetry:
     """JSONL telemetry sink + aggregator (one instance per run).
 
     ``flops_per_token`` / ``peak_flops_per_device`` / ``n_devices``
-    parameterize MFU (any of them 0 disables it); ``tokens_per_step``
+    parameterize MFU (a zero or None disables it); ``tokens_per_step``
     is the training global batch in tokens; ``drift`` is an optional
     :class:`DriftMonitor` priced from the ``--calib`` profile."""
 
     def __init__(self, run: str, *, path: Optional[str] = None,
                  out_dir: str = DEFAULT_DIR, tokens_per_step: int = 0,
                  flops_per_token: float = 0.0,
-                 peak_flops_per_device: float = 0.0, n_devices: int = 1,
+                 peak_flops_per_device: Optional[float] = None,
+                 n_devices: int = 1,
                  drift: Optional[DriftMonitor] = None, ema: float = 0.1,
                  meta: Optional[dict] = None, verbose: bool = True):
         self.run = run
         self.path = path or os.path.join(out_dir, f"{run}.jsonl")
         self.tokens_per_step = int(tokens_per_step)
         self.flops_per_token = float(flops_per_token)
-        self.peak_flops = float(peak_flops_per_device) * int(n_devices)
+        self.peak_flops = (float(peak_flops_per_device) * int(n_devices)
+                           if peak_flops_per_device else None)
         self.drift = drift
         self.ema_alpha = float(ema)
         self.verbose = verbose
@@ -268,7 +303,7 @@ class Telemetry:
         return rec
 
     def mfu(self, tok_s: float) -> Optional[float]:
-        if self.flops_per_token <= 0 or self.peak_flops <= 0:
+        if self.flops_per_token <= 0 or not self.peak_flops:
             return None
         return self.flops_per_token * tok_s / self.peak_flops
 

@@ -39,6 +39,7 @@ from repro.core.gradsync import GradSyncConfig
 from repro.core.partition import spec_tree_to_pspecs
 from repro.data.synthetic import DataConfig, SyntheticText, make_batch
 from repro.launch import mesh as LM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import steps as ST
 from repro.optim.adamw import AdamWConfig, init_state
 from repro.optim import adamw as OPT
@@ -186,6 +187,7 @@ def _ckpt_snapshot(path: str, cfg, axes, opts) -> dict:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     # resolve the calibration profile up front: a bad --calib path must
     # fail before the training loop, not after it
@@ -219,14 +221,13 @@ def main():
     cfg = preset_config(get_config(args.arch), args.preset)
     dtype = jnp.float32 if args.dtype == "float32" else jnp.bfloat16
 
-    params, specs = ST.init_model(cfg, axes, jax.random.PRNGKey(0),
-                                  dtype=dtype)
+    params, specs = ST.init_sharded(cfg, mesh, axes, jax.random.PRNGKey(0),
+                                    dtype=dtype)
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M "
           f"mesh={shape} devices={mesh.devices.size}")
 
     pspecs = spec_tree_to_pspecs(specs)
-    params = ST.device_put_tree(mesh, params, pspecs)
     if args.zero3:
         gs = GradSyncConfig(zero3=True, prefetch=args.zero3_prefetch,
                             bucket_mb=args.dp_bucket_mb)
@@ -301,8 +302,7 @@ def main():
             run_name, path=args.log_file or None,
             tokens_per_step=args.batch * args.seq,
             flops_per_token=CM.model_flops_per_token(cfg),
-            peak_flops_per_device=(calib_hw.flops if calib_hw is not None
-                                   else CM.TPU_V5E.flops),
+            peak_flops_per_device=TL.peak_flops_per_device(),
             n_devices=int(mesh.devices.size),
             drift=(TL.DriftMonitor(pred.total)
                    if pred is not None and pred.total > 0 else None),

@@ -347,7 +347,11 @@ def seq_attn(q, k, v, axes: M.MeshAxes, *, causal: bool = True,
     nkv, dv = k.shape[2], v.shape[-1]
     r = M.axis_index(axes.seq)
     q_pos = jnp.arange(C, dtype=jnp.int32) * p + r
-    carry = attn_partial_init(B, C, nkv, nq // nkv, dv)
+    # the carry meets per-seq-rank scores inside the scans, so it must
+    # enter them already varying over seq (shard_map's vma check)
+    carry = jax.tree.map(
+        lambda c: jax.lax.pcast(c, axes.seq, to="varying"),
+        attn_partial_init(B, C, nkv, nq // nkv, dv))
     if not axes.overlap.ring_attention:
         kg = M.all_gather(k, axes.seq, dim=1)
         vg = M.all_gather(v, axes.seq, dim=1)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
@@ -25,7 +26,6 @@ from repro.core import collective_matmul as CMM
 from repro.core import comm_model as CM
 from repro.core import mesh as M
 from repro.core import parallel as PP
-from repro.core.compat import shard_map
 from repro.core.gradsync import GradSyncConfig
 from repro.core.overlap import OverlapConfig
 from repro.core.partition import ParamSpec, expert_reduce_grads, spec_names
@@ -377,10 +377,18 @@ def _train_losses(shape, names=None, overlap=None, steps=3, B=8, S=32):
     return losses
 
 
+# The expert axis moves the MoE combine and the gradient sums onto other
+# devices, and XLA may order those fp32 reductions differently; the losses
+# then differ in the last bits (1 ulp, 1.7e-7 relative, was observed).
+# 1e-6 relative is ~8 fp32 ulps: far below any real routing or sharding
+# error, which moves the loss in its third digit.
+LOSS_RTOL = 1e-6
+
+
 def _parity_shapes():
     """(baseline, expert) shapes holding the token shards fixed: the
     expert axis replaces one factor of g_data, so dense layers see the
-    identical batch split and losses must match bitwise."""
+    identical batch split and losses must match to ``LOSS_RTOL``."""
     if N_DEVICES >= 8:
         return (2, 2, 2, 1), (1, 2, 2, 1, 2)
     return (2, 2, 1, 1), (1, 2, 1, 1, 2)
@@ -390,7 +398,7 @@ def test_expert_blocking_parity_with_data_axis():
     base, ex = _parity_shapes()
     l_base = _train_losses(base)
     l_blk = _train_losses(ex, EXPERT_NAMES)
-    assert l_blk == l_base, (l_blk, l_base)
+    np.testing.assert_allclose(l_blk, l_base, rtol=LOSS_RTOL)
     assert l_base[-1] < l_base[0]           # it actually trains
 
 
@@ -399,7 +407,7 @@ def test_expert_ring_parity_with_blocking():
     l_blk = _train_losses(ex, EXPERT_NAMES)
     l_ring = _train_losses(ex, EXPERT_NAMES,
                            overlap=OverlapConfig(expert_a2a=True))
-    assert l_ring == l_blk, (l_ring, l_blk)
+    np.testing.assert_allclose(l_ring, l_blk, rtol=LOSS_RTOL)
 
 
 def test_moe_init_rejects_nondividing_expert_axis():

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES, fitting_shapes
@@ -20,7 +21,6 @@ from repro.core import collective_matmul as CMM
 from repro.core import comm_model as CM
 from repro.core import mesh as M
 from repro.core import parallel as PP
-from repro.core.compat import shard_map
 from repro.core.overlap import OverlapConfig
 from repro.launch import mesh as LM
 from repro.launch import roofline as RL
@@ -390,12 +390,17 @@ def test_overlap_hlo_uses_collective_permute():
     blocking = _tp_collective_counts(None)
     ring = _tp_collective_counts(OverlapConfig(
         matmul=True, batched_matmul=True, tied_logits=True))
-    assert blocking.counts.get("all-gather", 0) >= 2
+    # the backward needs the same z-gathered weight as the forward; XLA
+    # may keep both gathers or merge them into one, so count at least one
+    assert blocking.counts.get("all-gather", 0) >= 1
     assert blocking.counts.get("reduce-scatter", 0) >= 1
     assert blocking.counts.get("collective-permute", 0) == 0
     assert ring.counts.get("all-gather", 0) == 0
     assert ring.counts.get("reduce-scatter", 0) == 0
-    assert ring.counts.get("collective-permute", 0) >= 3  # fwd + dX + dW
+    # z = 2: one hop per ring. The forward's gather ring and the dW
+    # reduce-scatter ring; XLA may give dX its own copy of the gather
+    # ring (3 hops) or reuse the forward's (2)
+    assert ring.counts.get("collective-permute", 0) >= 2
     # the overlap-aware estimate must see the ring traffic as hideable
     est_b = RL.step_time_estimate(1e9, blocking.bytes_by_kind)
     est_r = RL.step_time_estimate(1e9, ring.bytes_by_kind)

@@ -7,12 +7,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
 from repro.core import mesh as M
 from repro.core import parallel as PP
-from repro.core.compat import default_axis_types, make_mesh, shard_map
+from repro.launch import mesh as LM
 
 K, N, B, S = 16, 24, 8, 8
 
@@ -71,8 +72,7 @@ MESHES = [m for m in MESHES if math.prod(m[0]) <= N_DEVICES]
 @pytest.mark.parametrize("shape,names,bind", MESHES,
                          ids=[str(m[0]) + str(m[2].get("x")) for m in MESHES])
 def test_tp_matches_dense(shape, names, bind, data):
-    mesh = make_mesh(shape, names,
-                     axis_types=default_axis_types(len(names)))
+    mesh = LM.make_smoke_mesh(shape, names)
     axes = M.bind_axes(mesh, **bind)
     ref_val, ref_grads = _ref(data)
 

@@ -18,12 +18,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
 from repro.core import mesh as M
 from repro.core import parallel as PP
-from repro.core.compat import shard_map
 from repro.core.overlap import OverlapConfig
 from repro.kernels import ops
 from repro.layers import attention as A

@@ -6,11 +6,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import mesh as M
 from repro.core import parallel as PP
-from repro.core.compat import shard_map
 
 
 # --------------------------------------------------------------------- #

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
 from repro.configs import get_config
@@ -22,6 +23,24 @@ SHAPE0 = (2, 2, 2, 1) if N_DEVICES >= 8 else (1, 2, 2, 1)
 SHAPES_INV = ([(2, 2, 2, 1), (2, 1, 4, 1), (1, 2, 2, 2)]
               if N_DEVICES >= 8
               else [(1, 2, 2, 1), (2, 1, 2, 1), (1, 1, 2, 2)])
+
+
+def test_init_sharded_matches_init_model(mesh4, axes4):
+    """``init_sharded`` computes ``init_model``'s values straight into the
+    parameters' shardings. Under one jit XLA may fold an init scale
+    factor differently from the eager ops: a last-bit difference (1 fp32
+    ulp was observed), hence the tolerance."""
+    cfg = get_config("qwen3-1.7b").reduced()
+    key = jax.random.PRNGKey(3)
+    ref, specs = ST.init_model(cfg, axes4, key, dtype=jnp.float32)
+    got, _ = ST.init_sharded(cfg, mesh4, axes4, key, dtype=jnp.float32)
+    pspecs = spec_tree_to_pspecs(specs)
+    for r, g, s in zip(jax.tree.leaves(ref), jax.tree.leaves(got),
+                       jax.tree.leaves(pspecs,
+                                       is_leaf=lambda x: isinstance(x, P))):
+        assert g.sharding.mesh == mesh4 and g.sharding.spec == s
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-6,
+                                   atol=0)
 
 
 def _run(arch, mesh_shape, steps, *, seed=0, B=8, S=64, od=2):
@@ -80,7 +99,7 @@ def test_prefill_then_decode_consistent():
     """Prefill+decode must give the same next-token logits as running the
     full sequence through the train-mode forward."""
     from repro.models import decoder as D
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = LM.make_smoke_mesh(SHAPE0)

@@ -4,11 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
 from repro.core import mesh as M
-from repro.core.compat import shard_map
 from repro.core.partition import spec_tree_to_pspecs, unbox, z_reduce_grads
 from repro.launch import mesh as LM
 from repro.models import unet as U

@@ -19,13 +19,13 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES
 from repro.core import comm_model as CM
 from repro.core import gradsync as GS
 from repro.core import mesh as M
-from repro.core.compat import shard_map
 from repro.core.gradsync import GradSyncConfig
 from repro.core.partition import ParamSpec, spec_tree_to_pspecs
 from repro.launch import mesh as LM
