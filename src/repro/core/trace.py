@@ -1,19 +1,32 @@
-"""Named-scope trace attribution for the overlap schedules.
+"""Named-scope trace attribution: which layer of the model, and which
+ring schedule, an op of the compiled program belongs to.
 
-Every ring schedule in this repo — z weight AG/RS rings, x/y activation
-all-reduce rings, DP gradient bucket rings, ZeRO-3 param-shard streams,
-the seq-axis KV circulation — lowers to anonymous ``collective-permute``
-chains. A profiler trace (or an HLO dump) of a training step therefore
-cannot say WHICH schedule a given hop belongs to, which makes the
-"collectives hidden under compute" claim unverifiable op by op.
+A scope is a plain ``jax.named_scope``: its name lands in the
+``metadata op_name`` of every op traced inside it (``…/attn/dot_general``,
+``…/transpose(jvp(mlp))/…``), survives into the optimized HLO, and costs
+nothing on the device: only metadata differs from an unscoped build
+(tests/test_telemetry.py and tests/test_layer_scopes.py pin this).
 
-:func:`scope` fixes that: a context manager / decorator that wraps
-``jax.named_scope`` (names land in every op's ``metadata op_name``, so
-they survive into the optimized HLO and the profiler's HLO-op view) plus
-``jax.profiler.TraceAnnotation`` (host-side trace events around the
-tracing work itself). Scope names mirror the ``comm_model`` collective
-classes so a Perfetto trace maps one-to-one onto the analytic model's
-terms:
+Scopes are always on. There is no toggle, and there must not be one
+while JAX's persistent compile cache ignores metadata: its key strips
+debug info, so a build with scopes could load an executable compiled
+without them and the attribution would silently disappear.
+
+**Layer scopes** (:data:`LAYERS`) name where each layer's work happens;
+they nest and the innermost wins (``attn_core`` inside ``attn``):
+
+    vocab      embedding lookup; logits and vocab-parallel cross-entropy
+    norm       every LayerNorm / RMSNorm (models/decoder.py _apply_norm)
+    attn, mla, mamba, mlstm, slstm
+               a block's mixer and its residual add (_block_apply)
+    attn_core  the softmax core of attention: scores, softmax, PV
+    mlp, moe   a block's ffn and its residual add (_block_apply)
+    update     gradient accumulation, reductions, clip and the optimizer
+               update (launch/steps.make_train_step)
+
+**Ring scopes** (:func:`scope`) name the overlap schedules, which all
+lower to anonymous ``collective-permute`` chains. Their names mirror the
+``comm_model`` collective classes:
 
     ring_ag[z]/hop2          z weight all-gather ring, hop 2
     ring_rs[z]/hop0          z weight-grad reduce-scatter ring
@@ -22,40 +35,19 @@ terms:
     zero3_ag[data]/leaf7     ZeRO-3 just-in-time gather of leaf 7
     ring_exchange[seq]/hop1  ring-attention KV circulation, hop 1
     embed_gather[z]          embedding-table z gather
-
-**Zero overhead when disabled** (the default): :func:`scope` returns a
-shared no-op context manager — no ``named_scope`` is entered, so the
-lowered HLO is byte-identical to an uninstrumented build
-(tests/test_telemetry.py pins this). Enable with :func:`enable` or
-``REPRO_TRACE=1`` in the environment; ``train.py --profile-steps``
-enables it so the captured trace window carries attribution.
-
-Caveat: ``jit`` caches do not key on this flag — a function traced while
-disabled stays scope-free until retraced. Toggle before the first call
-(the CLIs do). The decorator form binds at decoration time for the same
-reason; instrumentation sites in this repo all use the ``with`` form.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
-import os
 from typing import Optional, Sequence, Union
+
+import jax
 
 AxisLike = Union[None, str, Sequence[str]]
 
-_ENABLED = os.environ.get("REPRO_TRACE", "").strip() not in ("", "0")
-
-
-def enabled() -> bool:
-    return _ENABLED
-
-
-def enable(on: bool = True) -> None:
-    """Turn scope emission on (or back off). Takes effect for functions
-    traced AFTER the call — see the jit-cache caveat in the module doc."""
-    global _ENABLED
-    _ENABLED = bool(on)
+#: The layer scope names, the whole vocabulary a reader of ``op_name``
+#: may attribute an op to.
+LAYERS = ("vocab", "norm", "attn", "attn_core", "mla", "mamba", "mlstm",
+          "slstm", "mlp", "moe", "update")
 
 
 def _axis_str(axis: AxisLike) -> str:
@@ -80,54 +72,14 @@ def label(kind: str, axis: AxisLike = None, detail: Optional[str] = None
     return name
 
 
-class _NullScope:
-    """Shared no-op: nothing enters ``named_scope``, so tracing under it
-    is bit-for-bit the uninstrumented lowering."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-    def __call__(self, fn):
-        return fn
-
-
-_NULL = _NullScope()
-
-
-class _Scope:
-    __slots__ = ("name", "_stack")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._stack = None
-
-    def __enter__(self):
-        import jax
-        self._stack = contextlib.ExitStack()
-        self._stack.enter_context(jax.named_scope(self.name))
-        self._stack.enter_context(jax.profiler.TraceAnnotation(self.name))
-        return self.name
-
-    def __exit__(self, *exc):
-        stack, self._stack = self._stack, None
-        return stack.__exit__(*exc)
-
-    def __call__(self, fn):
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with _Scope(self.name):
-                return fn(*args, **kwargs)
-        return wrapped
-
-
 def scope(kind: str, axis: AxisLike = None, detail: Optional[str] = None):
     """Context manager / decorator naming everything traced inside it
-    ``label(kind, axis, detail)``. A shared no-op when disabled."""
-    if not _ENABLED:
-        return _NULL
-    return _Scope(label(kind, axis, detail))
+    ``label(kind, axis, detail)``."""
+    return jax.named_scope(label(kind, axis, detail))
+
+
+def layer(name: str):
+    """The scope of one of :data:`LAYERS`."""
+    if name not in LAYERS:
+        raise ValueError(f"no layer scope {name!r}; known: {LAYERS}")
+    return jax.named_scope(name)

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import gradsync as GS
 from repro.core import mesh as M
 from repro.core import parallel as PP
+from repro.core import trace
 from repro.core.gradsync import GradSyncConfig
 from repro.core.overdecompose import split_batch
 from repro.core.overlap import OverlapConfig
@@ -236,46 +237,53 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, axes: M.MeshAxes,
             for i in range(n):
                 sub = jax.tree.map(lambda x: x[i], mb)
                 (li, mi), gi = vg(params, sub)
-                loss = li if loss is None else loss + li
-                metrics = mi if metrics is None else jax.tree.map(
-                    jnp.add, metrics, mi)
-                if stream:
-                    # bucket i's reduce-scatter launches here; microbatch
-                    # i+1's backward (next vg call) has no data dependency
-                    # on these ring hops, so the latency-hiding scheduler
-                    # can run the DP rings under its GEMMs — the same
-                    # overlap window the x/y/z rings use. fp32 shard
-                    # accumulation doubles as the mixed-precision fix.
-                    si = GS.reduce_scatter_grads(gi, plan, axes,
-                                                 ring=gs.ring)
-                    shards = (si if shards is None
-                              else [a + b for a, b in zip(shards, si)])
-                elif gs.zero3:
-                    # zero3: gi is already in the shard layout — each
-                    # leaf's gradient came out of the gather's transpose
-                    # as a ring reduce-scatter over data, streamed per
-                    # layer through this microbatch's own backward
-                    si = [g.astype(jnp.float32)
-                          for g in jax.tree.leaves(gi)]
-                    shards = (si if shards is None
-                              else [a + b for a, b in zip(shards, si)])
+                with trace.layer("update"):
+                    loss = li if loss is None else loss + li
+                    metrics = mi if metrics is None else jax.tree.map(
+                        jnp.add, metrics, mi)
+                    if stream:
+                        # bucket i's reduce-scatter launches here; microbatch
+                        # i+1's backward (next vg call) has no data dependency
+                        # on these ring hops, so the latency-hiding scheduler
+                        # can run the DP rings under its GEMMs — the same
+                        # overlap window the x/y/z rings use. fp32 shard
+                        # accumulation doubles as the mixed-precision fix.
+                        si = GS.reduce_scatter_grads(gi, plan, axes,
+                                                     ring=gs.ring)
+                        shards = (si if shards is None
+                                  else [a + b for a, b in zip(shards, si)])
+                    elif gs.zero3:
+                        # zero3: gi is already in the shard layout — each
+                        # leaf's gradient came out of the gather's transpose
+                        # as a ring reduce-scatter over data, streamed per
+                        # layer through this microbatch's own backward
+                        si = [g.astype(jnp.float32)
+                              for g in jax.tree.leaves(gi)]
+                        shards = (si if shards is None
+                                  else [a + b for a, b in zip(shards, si)])
+                    else:
+                        # accumulate in fp32: bf16 running sums lose ~1 ulp
+                        # per add, which compounds as overdecompose grows
+                        grads = (jax.tree.map(
+                            lambda g: g.astype(jnp.float32), gi)
+                            if grads is None else jax.tree.map(
+                                lambda a, g: a + g.astype(jnp.float32),
+                                grads, gi))
+            with trace.layer("update"):
+                loss = loss / n
+                metrics = jax.tree.map(lambda v: v / n, metrics)
+                if shards is not None:
+                    shards = [s / n for s in shards]
                 else:
-                    # accumulate in fp32: bf16 running sums lose ~1 ulp
-                    # per add, which compounds as overdecompose grows
-                    grads = (jax.tree.map(
-                        lambda g: g.astype(jnp.float32), gi)
-                        if grads is None else jax.tree.map(
-                            lambda a, g: a + g.astype(jnp.float32),
-                            grads, gi))
-            loss = loss / n
-            metrics = jax.tree.map(lambda v: v / n, metrics)
-            if shards is not None:
-                shards = [s / n for s in shards]
-            else:
-                grads = jax.tree.map(lambda g: g / n, grads)
+                    grads = jax.tree.map(lambda g: g / n, grads)
         else:
             (loss, metrics), grads = vg(params, batch)
 
+        with trace.layer("update"):
+            return _update(params, opt_state, loss, metrics, grads,
+                           shards)
+
+    def _update(params, opt_state, loss, metrics, grads, shards):
         if axes.gseq > 1:
             # params are replicated over seq; each seq-rank's grads hold
             # only its own tokens' contributions (the KV ring transposes

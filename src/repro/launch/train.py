@@ -160,10 +160,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "serializes with the device")
     ap.add_argument("--profile-steps", default="", metavar="A:B",
                     help="capture a jax.profiler trace of steps A..B "
-                         "(inclusive) to runs/profiles/<run>/, with "
-                         "named-scope attribution (core/trace.py) "
-                         "enabled so ring hops/buckets/gathers are "
-                         "labeled in the trace")
+                         "(inclusive) to runs/profiles/<run>/; ops carry "
+                         "the layer scopes (vocab, norm, attn, attn_core, "
+                         "mlp, update, ...) and ring scopes of "
+                         "core/trace.py in their op_name. Scopes are "
+                         "always on and never toggled: the persistent "
+                         "compile cache ignores metadata, so a toggled "
+                         "build could load a scope-free executable")
     ap.add_argument("--log-file", default="",
                     help="telemetry JSONL path (implies --telemetry; "
                          "default runs/telemetry/<run>.jsonl)")
@@ -198,15 +201,11 @@ def main():
 
     profile_steps = None
     if args.profile_steps:
-        from repro.core import trace
         a, _, b = args.profile_steps.partition(":")
         profile_steps = (int(a), int(b))
         if not (0 <= profile_steps[0] <= profile_steps[1]):
             raise SystemExit(f"--profile-steps {args.profile_steps}: "
                              f"need 0 <= A <= B")
-        # the captured window should attribute its ring hops; enable
-        # BEFORE the step is traced (jit caches don't key on the flag)
-        trace.enable()
 
     injector = None
     if args.chaos:

@@ -565,9 +565,12 @@ def attn_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
         if mode == "train" and axes.gseq > 1:
             # context parallelism: ring/blocking partial attention over
             # the striped seq shards (positions already carry the stripe)
-            out = seq_attn(q, k, v, axes, causal=causal, window=window)
+            with trace.layer("attn_core"):
+                out = seq_attn(q, k, v, axes, causal=causal,
+                               window=window)
         else:
-            out = attn_core(q, k, v, causal=causal, window=window)
+            with trace.layer("attn_core"):
+                out = attn_core(q, k, v, causal=causal, window=window)
         if mode == "prefill":
             kc, vc = cache["k"], cache["v"]
             kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
@@ -589,7 +592,8 @@ def attn_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
         ok = jk <= idx
         if window > 0:
             ok &= (idx - jk) < window
-        out = _decode_attn(q, kc, vc, ok)
+        with trace.layer("attn_core"):
+            out = _decode_attn(q, kc, vc, ok)
     elif mode == "paged":
         # continuous-batching serving (docs/serving.md): the cache is a
         # physical page pool (P_local, page, H_local, hd); each slot's
@@ -617,8 +621,9 @@ def attn_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
         # gathered (R, S_max, ...) view IS global position j
         kc = kp[table].reshape(R, -1, *kp.shape[2:])
         vc = vp[table].reshape(R, -1, *vp.shape[2:])
-        out = paged_attn_core(q, kc, vc, q_pos=positions, q_len=q_len,
-                              window=window)
+        with trace.layer("attn_core"):
+            out = paged_attn_core(q, kc, vc, q_pos=positions, q_len=q_len,
+                                  window=window)
     elif mode == "decode_seqshard":
         # global_batch=1 long-context: cache seq dim sharded over data; the
         # fresh token's kv is written by the owning shard only.
@@ -637,7 +642,9 @@ def attn_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
         kc = jax.lax.dynamic_update_slice(kc, kw, (0, safe, 0, 0))
         vc = jax.lax.dynamic_update_slice(vc, vw, (0, safe, 0, 0))
         new_cache = {"k": kc, "v": vc}
-        out = decode_core_seqsharded(q, kc, vc, pos, axes, window=window)
+        with trace.layer("attn_core"):
+            out = decode_core_seqsharded(q, kc, vc, pos, axes,
+                                         window=window)
     else:
         raise ValueError(mode)
 
@@ -820,7 +827,8 @@ def mla_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (B, T, nq_l, m.qk_rope_dim))],
             axis=-1)
-        out = attn_core(q, k, v, causal=True, scale=scale)
+        with trace.layer("attn_core"):
+            out = attn_core(q, k, v, causal=True, scale=scale)
         if mode == "prefill":
             cc = jax.lax.dynamic_update_slice(
                 cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0))

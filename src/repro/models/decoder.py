@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import mesh as M
 from repro.core import parallel as PP
+from repro.core import trace
 from repro.core.partition import Boxed
 from repro.layers import attention as A
 from repro.layers import mamba as MB
@@ -42,9 +43,10 @@ def _norm_init(cfg, axes, dtype, stack, abstract):
 
 
 def _apply_norm(p, h, cfg, axes):
-    if cfg.norm == "layernorm":
-        return PP.layer_norm(h, p["g"], p["b"], axes, cfg.d_model)
-    return PP.rms_norm(h, p["g"], axes, cfg.d_model)
+    with trace.layer("norm"):
+        if cfg.norm == "layernorm":
+            return PP.layer_norm(h, p["g"], p["b"], axes, cfg.d_model)
+        return PP.rms_norm(h, p["g"], axes, cfg.d_model)
 
 
 def _mixer_init(kind, key, cfg, axes, dtype, stack, abstract):
@@ -167,35 +169,38 @@ def _block_apply(blk, kinds_i, h, cfg, axes, *, positions, mode, cache,
     # decoder_paged_cache_specs gates the architecture up front.)
     sub_mode = "decode" if mode.startswith("decode") else mode
     hn = _apply_norm(blk["norm1"], h, cfg, axes)
-    if mixer == "attn":
-        o, cache = A.attn_apply(blk["mixer"], hn, cfg, axes,
-                                positions=positions, mode=mode, cache=cache,
-                                window=cfg.sliding_window, paged=paged)
-    elif mixer == "mla":
-        o, cache = A.mla_apply(blk["mixer"], hn, cfg, axes,
-                               positions=positions, mode=sub_mode,
-                               cache=cache)
-    elif mixer == "mamba":
-        o, cache = MB.mamba_apply(blk["mixer"], hn, cfg, axes,
-                                  mode=sub_mode, state=cache)
-    elif mixer == "mlstm":
-        o, cache = XL.mlstm_apply(blk["mixer"], hn, cfg, axes,
-                                  mode=sub_mode, state=cache)
-    elif mixer == "slstm":
-        o, cache = XL.slstm_apply(blk["mixer"], hn, cfg, axes,
-                                  mode=sub_mode, state=cache)
-    else:
-        raise ValueError(mixer)
-    h = h + o
+    with trace.layer(mixer):
+        if mixer == "attn":
+            o, cache = A.attn_apply(blk["mixer"], hn, cfg, axes,
+                                    positions=positions, mode=mode,
+                                    cache=cache, window=cfg.sliding_window,
+                                    paged=paged)
+        elif mixer == "mla":
+            o, cache = A.mla_apply(blk["mixer"], hn, cfg, axes,
+                                   positions=positions, mode=sub_mode,
+                                   cache=cache)
+        elif mixer == "mamba":
+            o, cache = MB.mamba_apply(blk["mixer"], hn, cfg, axes,
+                                      mode=sub_mode, state=cache)
+        elif mixer == "mlstm":
+            o, cache = XL.mlstm_apply(blk["mixer"], hn, cfg, axes,
+                                      mode=sub_mode, state=cache)
+        elif mixer == "slstm":
+            o, cache = XL.slstm_apply(blk["mixer"], hn, cfg, axes,
+                                      mode=sub_mode, state=cache)
+        else:
+            raise ValueError(mixer)
+        h = h + o
     if ffn != "none":
         hn = _apply_norm(blk["norm2"], h, cfg, axes)
-        if ffn == "moe":
-            o, a = MOE.moe_apply(blk["ffn"], hn, cfg, axes)
-            aux = aux + a
-        else:
-            o = FF.mlp_apply(blk["ffn"], hn, cfg.act, axes,
-                             gated=cfg.gated_mlp)
-        h = h + o
+        with trace.layer(ffn):
+            if ffn == "moe":
+                o, a = MOE.moe_apply(blk["ffn"], hn, cfg, axes)
+                aux = aux + a
+            else:
+                o = FF.mlp_apply(blk["ffn"], hn, cfg.act, axes,
+                                 gated=cfg.gated_mlp)
+            h = h + o
     return h, cache, aux
 
 
@@ -244,7 +249,8 @@ def decoder_hidden(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, *,
         else:
             positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32),
                                          (B, T))
-    h = PP.embedding_lookup(tokens, params["embed"], axes)
+    with trace.layer("vocab"):
+        h = PP.embedding_lookup(tokens, params["embed"], axes)
     if cfg.arch_type == "vlm" and image_embeds is not None:
         assert axes.gseq == 1, \
             "image_embeds need a contiguous token prefix (no seq sharding)"
@@ -404,9 +410,10 @@ def lm_loss(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, labels, *,
     B, T = labels.shape
 
     def chunk_loss(hc, lc):
-        logits = lm_logits(params, cfg, axes, hc)
-        return jnp.sum(PP.vocab_parallel_xent(logits, lc, axes,
-                                              cfg.vocab_size))
+        with trace.layer("vocab"):
+            logits = lm_logits(params, cfg, axes, hc)
+            return jnp.sum(PP.vocab_parallel_xent(logits, lc, axes,
+                                                  cfg.vocab_size))
 
     if xent_chunks > 1 and T % xent_chunks == 0:
         hs = h.reshape(B, xent_chunks, T // xent_chunks, -1)

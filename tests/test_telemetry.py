@@ -4,9 +4,9 @@ attribution (core/trace.py).
 Pins the observability contracts: the JSONL schema round-trips through
 its own validator, MFU math agrees with a hand count and with the
 roofline's model-flops constant, compiled HLO carries the scope names
-for a ring matmul and a ZeRO-3 gather when tracing is on, the disabled
-path is byte-identical to an uninstrumented build, and the drift monitor
-warns exactly once per out-of-band excursion.
+for a ring matmul and a ZeRO-3 gather, the scopes change nothing but
+metadata against an uninstrumented build, and the drift monitor warns
+exactly once per out-of-band excursion.
 """
 import contextlib
 import json
@@ -31,15 +31,6 @@ from repro.core import trace
 from repro.launch import mesh as LM
 from repro.launch import roofline as RL
 from repro.launch import telemetry as TL
-
-
-@pytest.fixture
-def traced():
-    """Enable scopes for one test; always restore the disabled default
-    (other tests pin the scope-free HLO)."""
-    trace.enable()
-    yield
-    trace.enable(False)
 
 
 # --------------------------------------------------------------------- #
@@ -150,16 +141,19 @@ def _has_scope(txt, name):
     return any(name in n for n in _op_names(txt))
 
 
+_METADATA = re.compile(r', metadata=\{(?:[^}"]|"(?:[^"\\]|\\.)*")*\}')
+
+
 def _program(txt):
-    """HLO text minus its stack-frame tables and ``stack_frame_id``s:
-    those record the Python call sites, which differ between two callers
-    of the same body while the program itself does not."""
-    return re.sub(r" stack_frame_id=\d+", "", _FRAME_TABLES.sub("\n", txt))
+    """HLO text minus its stack-frame tables and every instruction's
+    metadata: the tables and ``stack_frame_id``s record the Python call
+    sites, and ``op_name`` the scopes, neither of which is the program."""
+    return _METADATA.sub("", _FRAME_TABLES.sub("\n", txt))
 
 
 def _ring_ag_hlo():
-    """Fresh jit wrapper every call — jit caches do not key on the trace
-    flag, so each enable-state needs its own trace."""
+    """Fresh jit wrapper every call, so that a patched ``trace.scope``
+    is traced anew."""
     mesh = _z_mesh()
     axes = LM.bind_4d(mesh)
 
@@ -173,14 +167,14 @@ def _ring_ag_hlo():
     return jax.jit(f).lower(v, w).compile().as_text()
 
 
-def test_scopes_in_ring_matmul_hlo(traced):
+def test_scopes_in_ring_matmul_hlo():
     txt = _ring_ag_hlo()
     assert _has_scope(txt, "ring_ag[z]/hop0")
     assert _has_scope(txt, "gemm/chunk0")
     assert "collective-permute" in txt
 
 
-def test_scopes_in_zero3_and_dp_hlo(traced):
+def test_scopes_in_zero3_and_dp_hlo():
     shape = (4, 1, 2, 1) if N_DEVICES >= 8 else (4, 1, 1, 1)
     mesh = LM.make_smoke_mesh(shape)
     axes = LM.bind_4d(mesh)
@@ -206,7 +200,7 @@ def test_scopes_in_zero3_and_dp_hlo(traced):
     assert _has_scope(txt, "zero3_ag[data]/leaf0")
 
 
-def test_scopes_in_seq_kv_ring_hlo(traced):
+def test_scopes_in_seq_kv_ring_hlo():
     from repro.core.overlap import OverlapConfig
     from repro.layers import attention as A
     p = 4 if N_DEVICES >= 4 else 2
@@ -224,30 +218,23 @@ def test_scopes_in_seq_kv_ring_hlo(traced):
 
 
 def test_scope_disabled_hlo_byte_identical(monkeypatch):
-    """The degeneracy pin: with tracing off, ``scope`` must be a true
-    no-op — the compiled HLO is byte-for-byte what an uninstrumented
-    build produces (same body, ``scope`` patched to nullcontext, fresh
-    jit wrappers so nothing is cached across the comparison). The
-    comparison leaves out the stack-frame tables (:func:`_program`): the
-    patched ``scope`` is called from another source line."""
-    assert not trace.enabled()
+    """The zero-cost pin: scopes change only metadata. The compiled HLO
+    with scopes is, metadata and stack-frame tables left out
+    (:func:`_program`), byte-for-byte what an uninstrumented build
+    produces (same body, ``scope`` patched to nullcontext, fresh jit
+    wrappers so nothing is cached across the comparison)."""
     base = _ring_ag_hlo()
-    assert not _has_scope(base, "ring_ag[")
-    assert not _has_scope(base, "gemm/chunk")
+    assert _has_scope(base, "ring_ag[z]/hop0")
+    assert _has_scope(base, "gemm/chunk0")
 
     monkeypatch.setattr(trace, "scope",
                         lambda *a, **k: contextlib.nullcontext())
     stripped = _ring_ag_hlo()
+    # the scopes were there and are gone: the comparison is not vacuous
+    assert not _has_scope(stripped, "ring_ag[")
+    assert not _has_scope(stripped, "gemm/chunk")
+    assert base != stripped
     assert _program(base) == _program(stripped)
-
-    # sanity: the enabled path DOES change the text (the scopes above
-    # were not vacuously absent)
-    monkeypatch.undo()
-    trace.enable()
-    try:
-        assert _has_scope(_ring_ag_hlo(), "ring_ag[z]/hop0")
-    finally:
-        trace.enable(False)
 
 
 def test_scope_labels():
@@ -265,13 +252,12 @@ def test_scope_decorator_and_noop():
         calls.append(x)
         return x + 1
 
-    assert fn(1) == 2 and calls == [1]  # disabled: fn returned as-is
-    trace.enable()
-    try:
-        dec = trace.scope("k", None, "d")(lambda x: x * 2)
-        assert dec(3) == 6
-    finally:
-        trace.enable(False)
+    # the decorator calls through, every call, eagerly and when traced
+    assert fn(1) == 2 and fn(2) == 3 and calls == [1, 2]
+    txt = jax.jit(fn).lower(jnp.ones(3)).as_text(debug_info=True)
+    assert "k/d/" in txt
+    with pytest.raises(ValueError):
+        trace.layer("not_a_layer")
 
 
 # --------------------------------------------------------------------- #
