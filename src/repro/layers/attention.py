@@ -17,6 +17,7 @@ Decode supports two cache layouts:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -28,6 +29,7 @@ from repro.core import mesh as M
 from repro.core import parallel as PP
 from repro.core import trace
 from repro.core.partition import Boxed
+from repro.kernels import tpu_attention as TA
 from repro.layers.rotary import apply_rope, apply_rope_interleaved_neox
 
 NEG_INF = -1e30
@@ -56,8 +58,8 @@ def _softmax_fp32(scores):
 
 
 # ---------------------------------------------------------------------- #
-# attention core (pure jnp oracle; the Pallas flash kernel in
-# repro.kernels mirrors this and is validated against it)
+# attention core: jnp everywhere, the Pallas flash kernel of
+# kernels/tpu_attention on the TPU where it computes the same thing
 # ---------------------------------------------------------------------- #
 
 def attn_core(q, k, v, *, causal: bool = True, window: int = 0,
@@ -68,12 +70,35 @@ def attn_core(q, k, v, *, causal: bool = True, window: int = 0,
     ``q_pos0`` is the absolute position of q[:, 0] (for cached decode).
     ``window`` > 0 enables sliding-window attention (mistral-style).
     Long sequences route to the chunked online-softmax path (flash-style
-    O(T*chunk) memory — the jnp analogue of kernels/flash_attention)."""
+    O(T*chunk) memory).
+
+    Where ``tpu_attention.supports`` holds (bf16 self-attention from
+    position 0, as many K/V heads as q heads, no window, default scale,
+    a head dim and length the kernel's block rule tiles), a program
+    lowered for a TPU runs the Pallas flash kernel instead, at any
+    length; every other platform, and every other call, runs the jnp
+    core, which the kernel's tests take as their oracle. The choice is made at lowering
+    (``lax.platform_dependent``), so a program compiled ahead of time for
+    a TPU from a CPU process gets the kernel too."""
+    def jnp_core(q, k, v):
+        if max(q.shape[1], k.shape[1]) > chunked_threshold:
+            return attn_core_chunked(q, k, v, causal=causal, window=window,
+                                     q_pos0=q_pos0, scale=scale)
+        return _attn_core_dense(q, k, v, causal=causal, window=window,
+                                q_pos0=q_pos0, scale=scale)
+
+    if TA.supports(q, k, v, window=window, q_pos0=q_pos0, scale=scale):
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=functools.partial(TA.flash_mha, causal=causal),
+            default=jnp_core)
+    return jnp_core(q, k, v)
+
+
+def _attn_core_dense(q, k, v, *, causal, window, q_pos0, scale):
+    """:func:`attn_core`'s jnp core at short lengths: the whole fp32
+    score tensor, masked, its softmax, then PV."""
     B, Tq, nq, d = q.shape
     Tk, nkv = k.shape[1], k.shape[2]
-    if max(Tq, Tk) > chunked_threshold:
-        return attn_core_chunked(q, k, v, causal=causal, window=window,
-                                 q_pos0=q_pos0, scale=scale)
     g = nq // nkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     qg = q.reshape(B, Tq, nkv, g, d)

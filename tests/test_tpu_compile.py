@@ -11,6 +11,7 @@ this file. All such tests stay in this one file for the same reason.
 """
 import dataclasses
 import os
+import re
 
 import pytest
 
@@ -19,6 +20,10 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import chip_smoke as CS
+from bench import common as BC
+from bench import program as BP
+from bench import scopes as BS
+from repro.core.trace import LAYERS
 from repro.configs import get_config
 from repro.launch import mesh as LM
 from repro.launch import steps as ST
@@ -68,9 +73,10 @@ def _sharded(mesh, structs, pspecs):
         structs, pspecs)
 
 
-def _compile_train_step(cfg, mesh, axes):
-    """chip_smoke's train step, lowered from shapes on ``mesh``; returns
-    (compiled, bytes of the whole unsharded state)."""
+def _compile_train_step(cfg, mesh, axes, batch=CS.BATCH, seq=CS.SEQ):
+    """chip_smoke's train step (at ``batch`` x ``seq``), lowered from
+    shapes on ``mesh``; returns (compiled, bytes of the whole unsharded
+    state)."""
     topts = ST.TrainOptions(overdecompose=CS.OVERDECOMPOSE,
                             dtype=jnp.bfloat16)
     step_fn, _, _ = ST.make_train_step(cfg, mesh, axes, OPT.AdamWConfig(),
@@ -79,7 +85,7 @@ def _compile_train_step(cfg, mesh, axes):
     batch = {k: jax.ShapeDtypeStruct(st.shape, st.dtype,
                                      sharding=NamedSharding(mesh, spec))
              for k, (st, spec) in ST.batch_struct(
-                 cfg, axes, CS.BATCH, CS.SEQ, kind="train").items()}
+                 cfg, axes, batch, seq, kind="train").items()}
     compiled = step_fn.lower(_sharded(mesh, ps, pp), _sharded(mesh, ss, sp),
                              batch).compile()
     state_bytes = sum(x.size * x.dtype.itemsize
@@ -98,6 +104,36 @@ def test_one_chip_train_step_fits(one_chip):
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < HBM_BUDGET, (ma.argument_size_in_bytes,
                                ma.temp_size_in_bytes)
+
+
+def test_one_chip_attention_runs_the_flash_kernel(one_chip):
+    """The benchmark's one-chip cell (stablelm-1.6b-6l, MHA at head dim
+    64, 4 x 2048) still fits, its attention core runs the Pallas flash
+    kernel, and every kernel call, forward, remat recompute and the dK/dV
+    and dQ backward alike, carries the ``attn_core`` layer name that the
+    benchmark's device-time readers map (else it reads as unscoped)."""
+    mesh, axes = one_chip
+    cfg = BP.arch(BC.load_json(BC.ROOT / "bench" / "configs"
+                               / "stablelm-1.6b-6l.json"))
+    compiled, _ = _compile_train_step(cfg, mesh, axes, batch=4, seq=2048)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BUDGET
+    text = compiled.as_text()
+    smap = BS.instruction_layers(text, LAYERS)
+    calls = {}
+    for body in BS.computations(text).values():
+        for name, _, line in body:
+            if 'custom_call_target="tpu_custom_call"' in line:
+                on = re.search(r'op_name="([^"]*)"', line)
+                calls[name] = on.group(1) if on else ""
+    assert calls
+    assert {smap[n][1] for n in calls} == {"attn_core"}
+    kinds = [("forward", lambda t: not t.startswith("jit(step)/transpose")),
+             ("recompute", lambda t: "rematted_computation" in t),
+             ("dK/dV", lambda t: "bwd_dkv" in t),
+             ("dQ", lambda t: "bwd_dq" in t)]
+    for kind, test in kinds:
+        assert any(test(t) for t in calls.values()), (kind, calls)
 
 
 def test_one_chip_paged_serve_step_compiles(one_chip):
