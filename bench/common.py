@@ -1,9 +1,10 @@
 """Shared plumbing of the benchmark: paths, the cell's files, the chip
 check, the compile cache, seeds, host spans and the result line.
 
-Everything that belongs to one cell, configuration, traffic mix or
-per-layer metric lives in a file of its own under ``bench/`` and is found
-here by its name in ``BENCHMARK.json``.
+Everything that belongs to one cell, configuration, traffic mix,
+per-layer metric or model family lives in a file of its own under
+``bench/`` and is found here by its name in ``BENCHMARK.json`` or in the
+configuration file.
 """
 from __future__ import annotations
 
@@ -134,6 +135,60 @@ def load_reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+#: Where model families are found by name.
+FAMILIES = BENCH / "families"
+#: What every family module provides (see :func:`load_family`).
+FAMILY_API = ("arch", "canonical_shapes", "init", "to_program", "loss_mean",
+              "reference_spec", "train_flops_per_token", "tiny_configs")
+
+
+def load_family(name: str):
+    """The module ``bench/families/<name>.py``, the only model-specific
+    code of the harness. ``c`` is a configuration file's dict:
+
+    * ``arch(c)``: the program's ``ArchConfig``, refusing a configuration
+      the family or the program cannot run as it states;
+    * ``canonical_shapes(c)``: ``{leaf name: shape}`` of the seed's
+      weights; ``init(name, key, shape)``: one leaf in float32
+      (``bench.weights.canonical`` folds the index of the name, in
+      sorted order, into the seed's key and casts to ``torch_dtype``);
+    * ``to_program(w, c)``: canonical weights -> the program's parameter
+      tree, traceable;
+    * ``loss_mean(w, tokens, labels, c, mode)``: the plain float32
+      reference's loss, the quantity the program's step reports and
+      differentiates; its products through ``bench.reference.einsum``
+      (``mode`` "fp32", or "fp8" for the control); nothing of the
+      program imported;
+    * ``reference_spec(name, shape)``: the ``PartitionSpec`` of a
+      reference leaf over the cell's chips, a mesh of one axis ``"tp"``;
+    * ``train_flops_per_token(c, seq)``: by ``bench/flops.py``'s rules;
+    * ``tiny_configs()``: ``{variant: c}`` at sizes a CPU test can hold,
+      each kind of layer the family covers; variant names are unique
+      over all families.
+
+    A leaf whose name starts with ``layers.`` is a stack of layers on
+    axis 0, compared layer by layer (``reference.slice_norms``); a family
+    may hold several stacks, such as ``layers.dense.*`` and
+    ``layers.moe.*``."""
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in FAMILIES.glob("*.py"))
+        raise ValueError(f"no model family {name!r}; known: {known}")
+    spec = importlib.util.spec_from_file_location(f"bench_family_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"family {name!r} lacks {missing}")
+    return mod
+
+
+def family(c: Dict[str, Any]):
+    """The family of configuration ``c``: its ``family``, else dense."""
+    return load_family(c.get("family", "dense"))
 
 
 def emit(result: Dict[str, Any], checks: Dict[str, Dict[str, float]]):

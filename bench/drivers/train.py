@@ -20,7 +20,7 @@ from bench import program as PG
 from bench import reference as REF
 from bench import traffic as TR
 from bench import weights as W
-from bench.common import base_key
+from bench.common import base_key, family
 
 
 class Trainer:
@@ -49,9 +49,10 @@ class Trainer:
         self.bshard = {k: NamedSharding(self.mesh, v[1])
                        for k, v in bt.items()}
         c = self.c
+        to_program = family(c).to_program
 
         def make(key):
-            p = W.to_program(W.canonical(c, key), c)
+            p = to_program(W.canonical(c, key), c)
             return p, OPT.init_state(p)
         self._make = jax.jit(make, out_shardings=(pshard, sshard))
         names, treedef = jax.tree.flatten(W.program_names(c))
@@ -83,8 +84,8 @@ class Trainer:
         key = base_key(seed)
         self.params, self.state = self._make(key)
         n_feed = max(self.traffic["feed_batches"], check_steps)
-        self.host_batches = TR.train_batches(self.traffic, W.dims(self.c)["V"],
-                                             seed, n_feed)
+        self.host_batches = TR.train_batches(
+            self.traffic, self.c["vocab_size"], seed, n_feed)
         self.feed = [{"tokens": jax.device_put(t, self.bshard["tokens"]),
                       "labels": jax.device_put(l, self.bshard["labels"])}
                      for t, l in self.host_batches]
@@ -131,24 +132,15 @@ class Trainer:
 
 
 def reference_shardings(c: dict, devices):
-    """Tensor-parallel placement of the reference's float32 weights over
-    the cell's chips (matrices split on their output or input width),
-    so that weights, AdamW's moments and gradients fit beside each
-    other."""
-    from jax.sharding import Mesh, PartitionSpec as P
+    """Placement of the reference's float32 weights over the cell's
+    chips, by the family's ``reference_spec`` on a mesh of one axis
+    ``"tp"``, so that weights, AdamW's moments and gradients fit beside
+    each other."""
+    from jax.sharding import Mesh
     mesh = Mesh(np.asarray(devices), ("tp",))
-    last = ("layers.wq", "layers.wk", "layers.wv", "layers.wi",
-            "layers.wg", "lm_head", "embed")
-    out = {}
-    for name, shape in W.canonical_shapes(c).items():
-        if name in last:
-            spec = P(*([None] * (len(shape) - 1)), "tp")
-        elif name in ("layers.wo", "layers.wo_mlp"):
-            spec = P(None, "tp", None)
-        else:
-            spec = P()
-        out[name] = NamedSharding(mesh, spec)
-    return out
+    fam = family(c)
+    return {name: NamedSharding(mesh, fam.reference_spec(name, shape))
+            for name, shape in fam.canonical_shapes(c).items()}
 
 
 def reference_readings(c, opt, seed, host_batches, devices,

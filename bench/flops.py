@@ -1,7 +1,9 @@
-"""Model operations per token and the chips' peaks.
+"""Model operations per token, and the chips' peaks.
 
-Training counts what the forward and backward passes require, 3 x the
-forward (the backward is twice the forward), and no recompute:
+Each family counts its own (``train_flops_per_token`` in
+``bench/families/<family>.py``) by these rules. Training counts what
+the forward and backward passes require, 3 x the forward (the backward
+is twice the forward), and no recompute:
 
 * the weight matrices of every layer and of the output head, at 2 FLOPs
   per weight per token forward (6 with the backward);
@@ -17,24 +19,6 @@ this count is the benchmark's own.
 from __future__ import annotations
 
 from bench.common import BENCH, load_json
-from bench.weights import dims
-
-
-def matmul_weights(c: dict) -> int:
-    """Weights that multiply every token (layers and head)."""
-    z = dims(c)
-    attn = z["d"] * z["hd"] * (2 * z["H"] + 2 * z["KV"])
-    mlp = z["d"] * z["F"] * (3 if z["gated"] else 2)
-    return z["L"] * (attn + mlp) + z["d"] * z["V"]
-
-
-def attention_fwd_flops(c: dict, seq: int) -> float:
-    z = dims(c)
-    return z["L"] * 2.0 * z["H"] * z["hd"] * (seq + 1)
-
-
-def train_flops_per_token(c: dict, seq: int) -> float:
-    return 3.0 * (2.0 * matmul_weights(c) + attention_fwd_flops(c, seq))
 
 
 def peak(device_kind: str) -> dict:

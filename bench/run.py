@@ -47,8 +47,22 @@ def end_to_end(cell, values: dict) -> dict:
             for m in cell.end_to_end}
 
 
+def train_context(cell, red: dict, window: dict, chips: int,
+                  kind: str) -> dict:
+    """What the per-layer readers of a training cell read: the reduced
+    trace, the window's tokens and seconds, and the family's operations
+    per token with the chip's peaks."""
+    from bench import flops
+    c = cell.config
+    return {"trace": red, "window_s": window["window_s"], "chips": chips,
+            "tokens": window["tokens"],
+            "flops_per_token": C.family(c).train_flops_per_token(
+                c, cell.traffic["seq_len"]),
+            "peak": flops.peak(kind)}
+
+
 def run_train(cell, seed, seconds, trace, devices):
-    from bench import compare, flops
+    from bench import compare
     from bench import trace_reduce as TRR
     from bench.drivers import train as DT
     k = cell.spec["check_steps"]
@@ -69,12 +83,7 @@ def run_train(cell, seed, seconds, trace, devices):
     result = {"attempted": w["steps"], "failed": w["nonfinite"]}
     if trace:
         red = cap.read("bench.window")
-        S = cell.traffic["seq_len"]
-        ctx = {"trace": red, "window_s": w["window_s"], "chips": chips,
-               "tokens": w["tokens"],
-               "flops_per_token": flops.train_flops_per_token(cell.config,
-                                                              S),
-               "peak": flops.peak(device["kind"])}
+        ctx = train_context(cell, red, w, chips, device["kind"])
         result["metrics"] = per_layer(cell, ctx)
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         result["breakdown"] = TRR.breakdown(red)

@@ -28,8 +28,8 @@ CASES = [
 
 
 def _cell(name, chips):
-    cfg = (tiny.config(gated=False, act="gelu_new") if chips == 4
-           else tiny.config())
+    cfg = tiny.config("layernorm-plain-gelu-mha-partial-rotary" if chips == 4
+                      else "layernorm-gated-mha-partial-rotary")
     return tiny.cell(name, cfg, TRAIN_LIMITS, chips)
 
 

@@ -1,7 +1,10 @@
-"""The plain reference against the program at a small size on the CPU,
-both in float32: the loss of three steps, the first gradient as AdamW
-gets it, and the weights' change after the steps, for each kind of
-layer the reference covers, under the cells' own optimizer settings.
+"""Each model family against the program, one case per variant of each
+family file's ``tiny_configs()``: the family provides the whole
+contract, its weights take the program's parameter layout, and the
+plain reference follows the program at a small size on the CPU, both in
+float32: the loss of three steps, the first gradient as AdamW gets it,
+and the weights' change after the steps, under the cells' own optimizer
+settings. A family added as a file is checked here with no edit.
 
 The cells run AdamW with weight decay 0: at 0.1 the program decays no
 layer matrix (``repro.optim.adamw._no_decay`` matches ``pos`` in the
@@ -10,23 +13,37 @@ PERF.md lists that fault under Open questions."""
 import jax
 import pytest
 
+from bench import common as C
 from bench import compare
+from bench import program as PG
+from bench import weights as W
 from bench.drivers import train as DT
 from bench.tests import tiny
 
-VARIANTS = {
-    "layernorm-gated-mha-partial-rotary": tiny.config(dtype="float32"),
-    "rmsnorm-plain-gelu-gqa-full-rotary": tiny.config(
-        norm="rmsnorm", gated=False, act="gelu_new", kv=2, rot=1.0,
-        dtype="float32"),
-}
+VARIANTS = tiny.variants()
 
 
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
-def test_reference_follows_the_program(variant):
-    cell = tiny.cell("stablelm-1.6b.train-2k", VARIANTS[variant], {})
+def test_variant_names_are_unique():
+    names = [v for _, v, _ in VARIANTS]
+    assert len(names) == len(set(names)) and names
+
+
+@pytest.mark.parametrize("family,variant,cfg", VARIANTS,
+                         ids=[v for _, v, _ in VARIANTS])
+def test_reference_follows_the_program(family, variant, cfg):
+    fam = C.family(cfg)
+    assert fam.__file__ == str(C.FAMILIES / f"{family}.py")
+    for name in C.FAMILY_API:
+        assert callable(getattr(fam, name)), name
+    cfg = dict(cfg, torch_dtype="float32")
+    cell = tiny.cell("stablelm-1.6b.train-2k", cfg, {})
     devices = jax.devices()[:1]
     tr = DT.Trainer(cell, devices)
+    # to_program(canonical) has the structure and shapes of the
+    # program's abstract tree for arch(c), and names every leaf once
+    PG.param_layout(cfg, tr.axes)
+    named = jax.tree.leaves(W.program_names(cfg))
+    assert sorted(named) == sorted(fam.canonical_shapes(cfg))
     prog = tr.start(2**31 + 11, 3)
     ref = DT.reference_readings(cell.config, cell.spec["optimizer"],
                                 2**31 + 11, tr.host_batches[:3], devices)
@@ -36,3 +53,4 @@ def test_reference_follows_the_program(variant):
     assert numbers["grad_gap"] < 1e-4, numbers
     assert numbers["change_gap"] < 1e-4, numbers
     assert len(prog["loss"]) == 3 and prog["loss"][0] > prog["loss"][2]
+    assert fam.train_flops_per_token(cfg, cell.traffic["seq_len"]) > 0

@@ -146,7 +146,8 @@ def _executed(txt, comps):
 
 
 def test_tiny_step_maps_every_instruction(monkeypatch, capsys):
-    cell = tiny.cell("stablelm-1.6b.train-2k", tiny.config(),
+    cell = tiny.cell("stablelm-1.6b.train-2k",
+                     tiny.config("layernorm-gated-mha-partial-rotary"),
                      {"loss_gap": 1, "grad_gap": 1, "change_gap": 1})
     txt = DT.Trainer(cell, jax.devices()[:1]).step.as_text()
     # the readers' map: the step of the command line's cell, compiled

@@ -1,19 +1,21 @@
 """Tiny cells for the CPU tests: the cells' own drivers and mesh shapes,
-at widths a test run can hold."""
+with the families' own CPU-size configurations."""
 from __future__ import annotations
 
 from bench import common as C
 
-def config(norm="layernorm", gated=True, act="silu", kv=4, layers=2,
-           dtype="bfloat16", rot=0.25):
-    return {"program_config": "stablelm-1.6b", "hidden_size": 64,
-            "intermediate_size": 128, "num_attention_heads": 4,
-            "num_key_value_heads": kv, "head_dim": 16,
-            "num_hidden_layers": layers, "vocab_size": 512,
-            "hidden_act": act, "mlp_gated": gated, "norm_type": norm,
-            "layer_norm_eps": 1e-5 if norm == "layernorm" else 1e-6,
-            "partial_rotary_factor": rot, "rope_theta": 10000,
-            "tie_word_embeddings": False, "torch_dtype": dtype}
+
+def variants():
+    """(family, variant, configuration) for each ``tiny_configs()``
+    entry of each family file."""
+    return [(p.stem, name, cfg)
+            for p in sorted(C.FAMILIES.glob("*.py"))
+            for name, cfg in C.load_family(p.stem).tiny_configs().items()]
+
+
+def config(variant: str) -> dict:
+    """The tiny configuration of that name, of whichever family."""
+    return next(dict(cfg) for _, name, cfg in variants() if name == variant)
 
 
 def cell(name: str, cfg: dict, limits: dict, chips: int = 1):
