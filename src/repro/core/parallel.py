@@ -33,6 +33,7 @@ from repro.core import mesh as M
 from repro.core import trace
 from repro.core.overlap import OverlapConfig  # noqa: F401  (re-export)
 from repro.core.partition import Boxed
+from repro.kernels import grouped_matmul as GM
 
 # The overlap knobs (ring-decomposed z collectives, weight-gather caching)
 # ride on ``axes.overlap`` — an OverlapConfig bound via
@@ -327,6 +328,44 @@ def _tpbmm_bwd(axes, in_shard, out_shard, res, dy):
 
 
 tp_batched_matmul.defvjp(_tpbmm_fwd, _tpbmm_bwd)
+
+
+# ---------------------------------------------------------------------- #
+# grouped (per-expert, ragged) tp matmul: rows sorted by expert
+# ---------------------------------------------------------------------- #
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def tp_grouped_matmul(x, w, sizes, axes: M.MeshAxes,
+                      in_shard: Optional[str], out_shard: Optional[str]):
+    """Grouped matmul (``kernels.grouped_matmul``) with the blocking 4D
+    schedule of :func:`tp_batched_matmul`: x (m, k_local) rows sorted by
+    expert, w (E_local, k_local, n_local/z), sizes (E_local,) int32.
+    The weights are gathered over z; the product is all-reduced over
+    ``in_shard``; the backward all-reduces dX over ``out_shard`` and
+    reduce-scatters dW over z. Rows past ``sum(sizes)`` are unspecified,
+    forward and backward."""
+    wf = M.all_gather(w, axes.z, dim=2)
+    return M.psum(GM.grouped_matmul(x, wf, sizes),
+                  _logical(axes, in_shard))
+
+
+def _tpgmm_fwd(x, w, sizes, axes, in_shard, out_shard):
+    y = tp_grouped_matmul.__wrapped__(x, w, sizes, axes, in_shard,
+                                      out_shard)
+    return y, (x, w, sizes)
+
+
+def _tpgmm_bwd(axes, in_shard, out_shard, res, dy):
+    x, w, sizes = res
+    wf = M.all_gather(w, axes.z, dim=2)
+    _, vjp = jax.vjp(lambda a, b: GM.grouped_matmul(a, b, sizes), x, wf)
+    dx, dw = vjp(dy.astype(x.dtype))
+    dx = M.psum(dx, _logical(axes, out_shard))
+    dw = M.psum_scatter(dw.astype(jnp.float32), axes.z, dim=2)
+    return dx, dw.astype(w.dtype), None
+
+
+tp_grouped_matmul.defvjp(_tpgmm_fwd, _tpgmm_bwd)
 
 
 def tp_expert_init(key, n_experts: int, k: int, n: int,

@@ -21,6 +21,8 @@ they nest and the innermost wins (``attn_core`` inside ``attn``):
                a block's mixer and its residual add (_block_apply)
     attn_core  the softmax core of attention: scores, softmax, PV
     mlp, moe   a block's ffn and its residual add (_block_apply)
+    experts    inside moe: the routed experts' products (layers/moe.py);
+               moe keeps the router, dispatch, combine, shared experts
     update     gradient accumulation, reductions, clip and the optimizer
                update (launch/steps.make_train_step)
 
@@ -47,7 +49,7 @@ AxisLike = Union[None, str, Sequence[str]]
 #: The layer scope names, the whole vocabulary a reader of ``op_name``
 #: may attribute an op to.
 LAYERS = ("vocab", "norm", "attn", "attn_core", "mla", "mamba", "mlstm",
-          "slstm", "mlp", "moe", "update")
+          "slstm", "mlp", "moe", "experts", "update")
 
 
 def _axis_str(axis: AxisLike) -> str:

@@ -343,6 +343,8 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, axes: M.MeshAxes,
     mkeys = ["loss", "grad_norm", "lr", "xent"]
     if cfg.arch_type != "audio":
         mkeys.append("aux")
+        if "moe" in cfg.ffns():
+            mkeys += ["moe_held_share", "moe_load_max_mean", "moe_dropped"]
         if opts.mtp_weight > 0 and cfg.mtp_depth > 0:
             mkeys.append("mtp")
     mapped = shard_map(

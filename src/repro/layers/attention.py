@@ -30,7 +30,7 @@ from repro.core import parallel as PP
 from repro.core import trace
 from repro.core.partition import Boxed
 from repro.kernels import tpu_attention as TA
-from repro.layers.rotary import apply_rope, apply_rope_interleaved_neox
+from repro.layers.rotary import apply_rope, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -73,9 +73,10 @@ def attn_core(q, k, v, *, causal: bool = True, window: int = 0,
     O(T*chunk) memory).
 
     Where ``tpu_attention.supports`` holds (bf16 self-attention from
-    position 0, as many K/V heads as q heads, no window, default scale,
-    a head dim and length the kernel's block rule tiles), a program
-    lowered for a TPU runs the Pallas flash kernel instead, at any
+    position 0, as many K/V heads as q heads, no window, a v head dim no
+    wider than q's, a head dim and length the kernel's block rule tiles;
+    any scale), a program lowered for a TPU runs the Pallas flash kernel
+    instead, at any
     length; every other platform, and every other call, runs the jnp
     core, which the kernel's tests take as their oracle. The choice is made at lowering
     (``lax.platform_dependent``), so a program compiled ahead of time for
@@ -89,7 +90,8 @@ def attn_core(q, k, v, *, causal: bool = True, window: int = 0,
 
     if TA.supports(q, k, v, window=window, q_pos0=q_pos0, scale=scale):
         return jax.lax.platform_dependent(
-            q, k, v, tpu=functools.partial(TA.flash_mha, causal=causal),
+            q, k, v, tpu=functools.partial(TA.flash_mha, causal=causal,
+                                           scale=scale),
             default=jnp_core)
     return jnp_core(q, k, v)
 
@@ -822,8 +824,21 @@ def _mla_q(p, h, cfg, axes, positions):
         q = PP.tp_matmul(h, p["w_q"], axes, "x", "y")
     q = q.reshape(B, T, nq_l, qk_dim)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope_interleaved_neox(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                        yarn=cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def mla_scale(cfg) -> float:
+    """MLA's softmax scale: 1/sqrt(qk head dim), times YaRN's
+    ``mscale_all_dim`` temperature squared where the config stretches
+    the context (DeepSeek-V2)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
@@ -833,13 +848,13 @@ def mla_apply(p, h, cfg, axes: M.MeshAxes, *, positions, mode="train",
     m = cfg.mla
     nq_l = cfg.n_heads // axes.gy
     B, T = h.shape[:2]
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = mla_scale(cfg)
 
     dkv = PP.tp_matmul(h, p["w_dkv"], axes, "x", None)
     ckv, k_rope = dkv[..., :m.kv_lora_rank], dkv[..., m.kv_lora_rank:]
     ckv = _plain_rms(ckv, p["kv_norm"])
-    k_rope = apply_rope_interleaved_neox(k_rope[:, :, None, :], positions,
-                                         cfg.rope_theta)  # (B,T,1,rope)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        yarn=cfg.rope_scaling)  # (B,T,1,rope)
     q_nope, q_rope = _mla_q(p, h, cfg, axes, positions)
 
     new_cache = cache

@@ -20,13 +20,23 @@ class MoECfg:
     top_k: int
     d_expert: int
     n_shared: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # only with an expert axis (g_expert > 1)
     aux_loss_coef: float = 0.01
     z_loss_coef: float = 1e-3
     score_fn: str = "softmax"        # "softmax" | "sigmoid" (dsv3)
+    norm_topk: bool = True           # renormalise the top-k gates to sum 1
     routed_scale: float = 1.0
+    aux_form: str = "switch"         # "switch" (batch) | "seq" (dsv2)
+    n_held: int = 0                  # experts this model holds; 0 = all
     first_dense: int = 0             # leading dense layers
     period: int = 1                  # MoE every `period` layers (jamba: 2)
+
+    @property
+    def n_bank(self) -> int:
+        """Routed experts whose weights this model holds (the chip's
+        share of a stated deployment): global ids 0 .. n_bank - 1 of the
+        router's ``n_experts``."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +46,18 @@ class MLACfg:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 configures
+    it (``rope_scaling`` of type ``yarn``)."""
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +100,7 @@ class ArchConfig:
     qk_norm: bool = False
     sliding_window: int = 0          # 0 = full attention
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnCfg] = None
     rotary_pct: float = 1.0
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -169,7 +192,11 @@ class ArchConfig:
     def segments(self) -> Tuple[Tuple[Tuple[Tuple[str, str], ...], int], ...]:
         """Greedy segmentation of layer_kinds() into (period_kinds,
         n_periods) runs, so e.g. DeepSeek-V3's 3-dense prefix + 58 MoE
-        body becomes two scanned segments instead of 61 distinct layers."""
+        body becomes two scanned segments instead of 61 distinct layers.
+        At each layer the run of a repeating period (two periods or more)
+        that covers the most layers is taken, the shorter period on a
+        tie; where no period repeats, the layer stands alone (a prefix is
+        never fused with the run after it into one unscanned period)."""
         kinds = self.layer_kinds()
         n = len(kinds)
         segs = []
@@ -181,8 +208,7 @@ class ArchConfig:
                 r = 1
                 while kinds[i + r * p: i + (r + 1) * p] == pat:
                     r += 1
-                if (p * r > best[0] * best[1]
-                        or (p * r == best[0] * best[1] and p < best[0])):
+                if r > 1 and (best[1] == 1 or p * r > best[0] * best[1]):
                     best = (p, r)
             p, r = best
             segs.append((kinds[i:i + p], r))
@@ -232,7 +258,7 @@ class ArchConfig:
                 mc = self.moe
                 mult = 2 if self.gated_mlp else 1
                 per = (mult + 1) * d * mc.d_expert
-                total += mc.n_experts * per + mc.n_shared * per + d * mc.n_experts
+                total += mc.n_bank * per + mc.n_shared * per + d * mc.n_experts
         return total
 
     def active_param_count(self) -> int:
@@ -243,7 +269,8 @@ class ArchConfig:
         mult = 2 if self.gated_mlp else 1
         per = (mult + 1) * self.d_model * mc.d_expert
         n_moe = sum(1 for f in self.ffns() if f == "moe")
-        inactive = n_moe * (mc.n_experts - mc.top_k) * per
+        held_active = mc.top_k * mc.n_bank / mc.n_experts
+        inactive = int(n_moe * (mc.n_bank - held_active) * per)
         return self.param_count() - inactive
 
     # ------------------------------------------------------------------ #
@@ -310,7 +337,7 @@ class ArchConfig:
         # wastes KV-cache memory, so the optimizer still prefers small y.
         y_divs = [self.n_heads]
         if self.moe:
-            y_divs.append(self.moe.n_experts)
+            y_divs.append(self.moe.n_bank)
         if self.xlstm:
             y_divs = [self.n_heads]
         return Constraints(global_batch=global_batch,
@@ -325,8 +352,8 @@ class ArchConfig:
         if (self.mla is None and self.n_kv_heads % axes.gy
                 and axes.gy % self.n_kv_heads):
             return f"kv heads {self.n_kv_heads} vs gy {axes.gy}"
-        if self.moe and self.moe.n_experts % axes.gy:
-            return f"experts {self.moe.n_experts} % gy {axes.gy}"
+        if self.moe and self.moe.n_bank % axes.gy:
+            return f"experts {self.moe.n_bank} % gy {axes.gy}"
         if axes.gseq > 1:
             # context parallelism needs softmax attention everywhere:
             # recurrent mixers (mamba/xlstm) and MLA's materialized path
@@ -340,12 +367,15 @@ class ArchConfig:
                         f"{self.arch_type} (contiguous-prefix inputs)")
             if self.max_seq % axes.gseq:
                 return f"max_seq {self.max_seq} % g_seq {axes.gseq}"
+        if axes.gseq > 1 and self.moe and self.moe.aux_form == "seq":
+            return (f"the sequence-wise balance loss needs whole sequences "
+                    f"on a chip, not a seq axis of g_seq={axes.gseq}")
         if axes.gexpert > 1:
             if self.moe is None:
                 return (f"expert axis (g_expert={axes.gexpert}) needs an "
                         f"MoE architecture")
-            if self.moe.n_experts % (axes.gy * axes.gexpert):
-                return (f"experts {self.moe.n_experts} % gy*g_expert "
+            if self.moe.n_bank % (axes.gy * axes.gexpert):
+                return (f"experts {self.moe.n_bank} % gy*g_expert "
                         f"{axes.gy * axes.gexpert}")
         return None
 

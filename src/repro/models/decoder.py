@@ -195,8 +195,12 @@ def _block_apply(blk, kinds_i, h, cfg, axes, *, positions, mode, cache,
         hn = _apply_norm(blk["norm2"], h, cfg, axes)
         with trace.layer(ffn):
             if ffn == "moe":
-                o, a = MOE.moe_apply(blk["ffn"], hn, cfg, axes)
-                aux = aux + a
+                o, a, counters = MOE.moe_apply(blk["ffn"], hn, cfg, axes)
+                loss, stats = aux
+                # rows in layer order: shift out the first, append this
+                # layer's (after the last MoE layer each row is filled)
+                aux = (loss + a, jnp.concatenate([stats[1:],
+                                                  counters[None]]))
             else:
                 o = FF.mlp_apply(blk["ffn"], hn, cfg.act, axes,
                                  gated=cfg.gated_mlp)
@@ -216,7 +220,9 @@ def decoder_hidden(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, *,
                    image_embeds=None, remat: bool = True,
                    unroll: bool = False, remat_policy: str = "full",
                    pstream=None, paged=None):
-    """Run embedding + all blocks. Returns (h, new_caches, aux_loss).
+    """Run embedding + all blocks. Returns (h, new_caches, (aux_loss,
+    moe_counters)): ``moe_counters`` is float32[n_moe_layers, 3] of
+    ``layers.moe._counters``, or None without MoE layers.
 
     ``pstream`` (a ``gradsync.ParamStreamer``, zero3 training only)
     switches the segment params to the ZeRO-3 shard layout: each scan
@@ -262,7 +268,10 @@ def decoder_hidden(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, *,
         h = jax.lax.dynamic_update_slice(
             h, v.astype(h.dtype), (0, 0, 0))
 
-    aux0 = jnp.zeros((), jnp.float32)
+    # (aux loss, MoE counters: one float32[3] row per MoE layer, or None)
+    n_moe = cfg.ffns().count("moe")
+    aux0 = (jnp.zeros((), jnp.float32),
+            jnp.zeros((n_moe, 3), jnp.float32) if n_moe else None)
 
     def make_period_fn(kinds):
         def period_fn(h, aux, blk_params, blk_caches):
@@ -403,10 +412,10 @@ def lm_loss(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, labels, *,
     per-layer through ``decoder_hidden``."""
     if pstream is not None:
         params = pstream.resident(params)
-    h, _, aux = decoder_hidden(params, cfg, axes, tokens, mode="train",
-                               image_embeds=image_embeds, remat=remat,
-                               unroll=unroll, remat_policy=remat_policy,
-                               pstream=pstream)
+    h, _, (aux, moe_counters) = decoder_hidden(
+        params, cfg, axes, tokens, mode="train", image_embeds=image_embeds,
+        remat=remat, unroll=unroll, remat_policy=remat_policy,
+        pstream=pstream)
     B, T = labels.shape
 
     def chunk_loss(hc, lc):
@@ -433,6 +442,11 @@ def lm_loss(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, labels, *,
     aux_mean = PP.ar_bwd_identity(aux, axes.token_axes()) / axes.token_shards
     out_loss = loss + aux_mean
     metrics = {"xent": loss, "aux": aux_mean}
+    if moe_counters is not None:
+        # per MoE layer, the mean over batch shards (and microbatches)
+        c = M.psum(moe_counters, axes.token_axes()) / axes.token_shards
+        metrics.update(moe_held_share=c[:, 0], moe_load_max_mean=c[:, 1],
+                       moe_dropped=c[:, 2])
     if mtp_weight > 0.0 and "mtp" in params and T > 2:
         assert axes.gseq == 1, \
             "MTP needs contiguous token shifts (no seq sharding)"
@@ -449,7 +463,7 @@ def lm_loss(params, cfg: ArchConfig, axes: M.MeshAxes, tokens, labels, *,
         hm, _, _ = (lambda hh: _block_apply(
             mtp["block"], (cfg.mixers()[-1], "mlp"), hh, cfg, axes,
             positions=pos, mode="train", cache=None,
-            aux=jnp.zeros((), jnp.float32)))(hm)
+            aux=(jnp.zeros((), jnp.float32), None)))(hm)
         logits_m = lm_logits(params, cfg, axes, hm)
         mtp_tok = PP.vocab_parallel_xent(logits_m, labels[:, 1:-1], axes,
                                          cfg.vocab_size)

@@ -43,9 +43,9 @@ def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
-def _jnp_core(q, k, v, causal):
+def _jnp_core(q, k, v, causal, scale=None):
     return A._attn_core_dense(q, k, v, causal=causal, window=0, q_pos0=0,
-                              scale=None)
+                              scale=scale)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -76,6 +76,43 @@ def test_kernel_matches_jnp_core(monkeypatch, causal, d, n_blocks):
         assert _rel(g, r) < RTOL
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("n_blocks", [1, 2])
+def test_mla_head_matches_jnp_core(monkeypatch, causal, n_blocks):
+    """MLA's core (DeepSeek-V2: q/k head 192 = 128 nope + 64 rope, v head
+    128, the YaRN softmax scale mscale^2 / sqrt(192) = 0.114721): q and k
+    run zero-padded to the kernel's head dim 256, v too, and the output
+    is sliced back to 128 columns; forward and the q, k, v gradients
+    under full remat against the jnp core at the same scale."""
+    d, dv, scale = 192, 128, 0.114721
+    assert TA.kernel_dim(d) == 256
+    monkeypatch.setitem(TA.MAX_BLOCK, 256, TA.MIN_BLOCK)
+    T = TA.MIN_BLOCK * n_blocks
+    q, k, v, do = _qkv(1, T, 2, d, dv=dv, seed=10 + n_blocks)
+    assert TA.supports(q, k, v, scale=scale)
+    assert T // TA.block_size(T, d) == n_blocks
+
+    def loss(core):
+        f = jax.checkpoint(lambda q, k, v: core(q, k, v).astype(jnp.float32))
+        return lambda q, k, v: jnp.sum(f(q, k, v) * do)
+
+    kernel = lambda q, k, v: TA.flash_mha(q, k, v, causal=causal,  # noqa
+                                          scale=scale)
+    oracle = lambda q, k, v: _jnp_core(q, k, v, causal, scale)  # noqa
+    with pltpu.force_tpu_interpret_mode(True):
+        out = jax.jit(kernel)(q, k, v)
+        grads = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, v)
+    ref = oracle(q, k, v)
+    ref_grads = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    assert out.dtype == q.dtype and out.shape == (1, T, 2, dv)
+    assert _rel(out, ref) < RTOL
+    for g, r in zip(grads, ref_grads):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        assert _rel(g, r) < RTOL
+    # the scale is the one given: the default 1/sqrt(192) is another core
+    assert _rel(_jnp_core(q, k, v, causal), ref) > 10 * RTOL
+
+
 def test_block_rule():
     """The largest power of two up to MAX_BLOCK[d] that divides T."""
     assert TA.block_size(2048, 64) == TA.MAX_BLOCK[64] == 1024
@@ -85,6 +122,10 @@ def test_block_rule():
     assert TA.block_size(100, 64) == 0       # under the smallest block
     assert TA.block_size(2048 + 64, 64) == 0  # not a block multiple
     assert TA.block_size(2048, 96) == 0      # a head dim the rule lacks
+    # wider heads run padded to whole 128 lanes: MLA's 192 as 256
+    assert TA.kernel_dim(64) == 64 and TA.kernel_dim(128) == 128
+    assert TA.kernel_dim(192) == TA.kernel_dim(256) == 256
+    assert TA.block_size(8192, 192) == TA.MAX_BLOCK[256]
 
 
 def _takes_kernel(q, k, v, **kw):
@@ -103,15 +144,28 @@ def test_dispatch_takes_kernel(T, causal):
     assert _takes_kernel(q, k, v, causal=causal)
 
 
+@pytest.mark.parametrize("case", ["mla", "scale"])
+def test_dispatch_takes_kernel_for_mla(case):
+    """MLA's narrower v with its own scale at its 192-wide q/k head, and a
+    passed scale alone: the kernel too."""
+    if case == "mla":
+        q, k, v, _ = _qkv(1, 256, 2, 192, dv=128)
+        kw = {"scale": 0.114721}
+    else:
+        q, k, v, _ = _qkv(1, 256, 2, 64)
+        kw = {"scale": 64 ** -0.5}
+    assert TA.supports(q, k, v, **kw)
+    assert _takes_kernel(q, k, v, causal=True, **kw)
+
+
 @pytest.mark.parametrize("case", [
-    "gqa", "window", "mla", "scale", "cross", "offset", "untiled", "fp32",
+    "gqa", "window", "mla", "cross", "offset", "untiled", "fp32",
     "head_dim"])
 def test_dispatch_falls_back(case):
     """Every call the kernel would compute differently keeps the jnp
-    core: GQA, a sliding window, MLA's dv != dq with its own scale, a
-    passed scale, cross-attention (Tq != Tk), a cached prefill at an
-    offset, a length no block tiles, fp32 operands, a head dim the block
-    rule lacks."""
+    core: GQA, a sliding window, a v head wider than q's, cross-attention
+    (Tq != Tk), a cached prefill at an offset, a length no block tiles,
+    fp32 operands, a head dim the block rule lacks."""
     B, T, H, d = 1, 256, 4, 64
     kw = {}
     shape = dict(B=B, T=T, H=H, d=d)
@@ -120,10 +174,8 @@ def test_dispatch_falls_back(case):
     elif case == "window":
         kw["window"] = 64
     elif case == "mla":
-        shape["dv"] = 32
+        shape["dv"] = 128
         kw["scale"] = 0.1
-    elif case == "scale":
-        kw["scale"] = d ** -0.5
     elif case == "cross":
         shape["Tk"] = 384
         kw["causal"] = False

@@ -352,6 +352,12 @@ def _train_losses(shape, names=None, overlap=None, steps=3, B=8, S=32):
     from repro.optim.adamw import AdamWConfig, init_state
 
     cfg = get_config("deepseek-v2-lite-16b").reduced()
+    # a capacity no expert queue can overflow (tokens x top_k / E slots
+    # times E / top_k): the expert axis's capacity dispatch then drops
+    # nothing, as the dropless g_expert = 1 dispatch never does
+    mc = cfg.moe
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        mc, capacity_factor=mc.n_experts / mc.top_k))
     mesh = LM.make_smoke_mesh(
         shape, names or ("data", "x", "y", "z")[:len(shape)])
     axes = LM.bind_4d(mesh)

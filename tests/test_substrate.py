@@ -125,10 +125,6 @@ def test_moe_matches_dense_loop(mesh4, axes4):
     pspecs = spec_tree_to_pspecs(specs)
     hspec = axes4.pspec(axes4.batch_axes(), None, axes4.x)
 
-    def par(params, h):
-        out, aux = MOE.moe_apply(params, cfg, axes4, h)
-        return out
-
     f = shard_map(lambda p, h: MOE.moe_apply(p, h, cfg, axes4)[0],
                   mesh=mesh4, in_specs=(pspecs, hspec), out_specs=hspec,
                   check_vma=False)

@@ -73,11 +73,12 @@ def _sharded(mesh, structs, pspecs):
         structs, pspecs)
 
 
-def _compile_train_step(cfg, mesh, axes, batch=CS.BATCH, seq=CS.SEQ):
+def _compile_train_step(cfg, mesh, axes, batch=CS.BATCH, seq=CS.SEQ,
+                        overdecompose=CS.OVERDECOMPOSE):
     """chip_smoke's train step (at ``batch`` x ``seq``), lowered from
     shapes on ``mesh``; returns (compiled, bytes of the whole unsharded
     state)."""
-    topts = ST.TrainOptions(overdecompose=CS.OVERDECOMPOSE,
+    topts = ST.TrainOptions(overdecompose=overdecompose,
                             dtype=jnp.bfloat16)
     step_fn, _, _ = ST.make_train_step(cfg, mesh, axes, OPT.AdamWConfig(),
                                        topts)
@@ -134,6 +135,51 @@ def test_one_chip_attention_runs_the_flash_kernel(one_chip):
              ("dQ", lambda t: "bwd_dq" in t)]
     for kind, test in kinds:
         assert any(test(t) for t in calls.values()), (kind, calls)
+
+
+def _custom_calls(text):
+    """Each TPU custom call of an HLO text -> its ``op_name``."""
+    calls = {}
+    for body in BS.computations(text).values():
+        for name, _, line in body:
+            if 'custom_call_target="tpu_custom_call"' in line:
+                on = re.search(r'op_name="([^"]*)"', line)
+                calls[name] = on.group(1) if on else ""
+    return calls
+
+
+def test_one_chip_moe_cell_runs_its_kernels(one_chip):
+    """The benchmark's MoE cell (deepseek-v2-lite-16b-6l-8e at its
+    traffic's batch and its cell's options) fits one chip; the MLA core
+    runs the flash kernel and no fp32 score tensor of the jnp core, whole
+    or per block, is left; the held experts run the grouped-matmul
+    kernel, forward, recompute, dX and dW (``gmm``, ``tgmm``); every
+    custom call carries the layer name the benchmark maps, ``attn_core``
+    or ``experts``."""
+    mesh, axes = one_chip
+    name = "deepseek-v2-lite-16b.train-moe-8k"
+    cell = BC.load_cell(name)
+    cfg = BP.arch(cell.config)
+    B, T = cell.traffic["global_batch"], cell.traffic["seq_len"]
+    compiled, _ = _compile_train_step(
+        cfg, mesh, axes, batch=B, seq=T,
+        overdecompose=cell.spec["train_options"]["overdecompose"])
+    text = compiled.as_text()
+    smap = BS.instruction_layers(text, LAYERS)
+    calls = _custom_calls(text)
+    assert {smap[n][1] for n in calls} == {"attn_core", "experts"}
+    experts = [t for n, t in calls.items() if smap[n][1] == "experts"]
+    kinds = [("forward", lambda t: "jvp(" in t and "transpose" not in t
+              and "rematted" not in t),
+             ("recompute", lambda t: "rematted_computation" in t),
+             ("dX", lambda t: "transpose" in t and "jit(gmm)" in t),
+             ("dW", lambda t: "jit(tgmm)" in t)]
+    for kind, test in kinds:
+        assert any(test(t) for t in experts), (kind, experts)
+    assert any("flash_mha_bwd_dq" in t for t in calls.values())
+    # the jnp core's scores: (.., T, T) whole, (.., 512, 1024) per block
+    assert not re.search(rf"f32\[[0-9,]*{T},{T}\]", text)
+    assert not re.search(r"f32\[[0-9,]*,512,1024\]", text)
 
 
 def test_one_chip_paged_serve_step_compiles(one_chip):
