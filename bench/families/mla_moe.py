@@ -168,6 +168,8 @@ def to_program(w: Dict[str, jax.Array], c: dict) -> dict:
 
 
 def _f32(x):
+    """The router's weights in float32 (a leaf name, from
+    ``weights.program_names``, passes through)."""
     return x if isinstance(x, str) else x.astype(jnp.float32)
 
 
@@ -386,8 +388,8 @@ def loss_mean(w, tokens, labels, c: dict, mode: str = "fp32"):
 
 
 def reference_spec(name: str, shape) -> P:
-    """The vocabulary and the held experts split over the chips; the
-    rest replicated."""
+    """The embedding and head (on their last dim) and the held experts
+    split over the chips; the rest replicated."""
     if name in ("embed", "lm_head"):
         return P(None, "tp")
     if ".experts." in name:

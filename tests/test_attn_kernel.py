@@ -5,8 +5,9 @@ The kernel runs here in Pallas's HLO interpret mode
 (``force_tpu_interpret_mode(True)``; the callback-based TPU simulator has
 effects that ``jax.checkpoint`` refuses), at tiny shapes: forward
 and the gradients of q, k and v under ``jax.checkpoint`` (full remat, as
-the decoder applies it), causal and not, at head dims 64 and 128, over
-one block and several. ``MAX_BLOCK`` is lowered in a test to tile a
+the decoder applies it), causal and not, at head dims 64 and 128 and at
+MLA's 192 with a narrower v and its own scale, over one block and
+several. ``MAX_BLOCK`` is lowered in a test to tile a
 short sequence into several blocks.
 
 The tolerance is fixed by the dtype, not fitted: both sides take bf16
