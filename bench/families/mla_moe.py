@@ -32,12 +32,13 @@ published ``modeling_deepseek.py`` equations, not the program:
   mean_t p_{t,e}, averaged over the sequences, added to the mean
   cross-entropy.
 
-Memory: each stack of layers under a scan with rematerialization; in a
-layer each sequence's attention and feed-forward one at a time (each
-recomputed in the backward), the held experts one at a time, attention
-blocked over queries (each block takes its exact softmax over every key,
-the later ones masked), and the head one sequence at a time, so the
-reference fits beside its weights and AdamW's moments on one chip at 8k.
+Memory: each stack of layers under a scan that keeps only the layers'
+inputs for the backward; in a layer one
+sequence at a time (recomputed in the backward), the held experts one at
+a time, attention blocked over queries (each block takes its exact
+softmax over every key, the later ones masked), and the final norm and
+head one sequence at a time, so the reference fits beside its weights,
+AdamW's moments and its gradient on one chip at 8 x 8192.
 """
 from __future__ import annotations
 
@@ -52,7 +53,7 @@ from jax.sharding import PartitionSpec as P
 from bench.reference import einsum
 
 #: Query rows per block of the reference's attention.
-ATTN_BLOCK = 512
+ATTN_BLOCK = 128
 #: The program's fixed RMSNorm epsilon.
 _EPS = 1e-6
 
@@ -341,46 +342,53 @@ def _rows(f, *xs):
     return jax.lax.map(jax.checkpoint(lambda a: f(*a)), xs)
 
 
-def hidden(w, tokens, z, es):
-    """tokens (B, T) -> (final-normed hidden states (B, T, d), each
-    sequence's balance loss summed over the MoE layers (B,)). The layers
-    run under a scan with rematerialization, each sequence's attention
-    and feed-forward one at a time."""
-    h = w["embed"][tokens]
-    carry = (h, jnp.zeros(tokens.shape[0], jnp.float32))
-    for kind in ("dense", "moe"):
-        pre = f"layers.{kind}."
+def _layer(hr, lw, kind, z, es):
+    """One sequence (T, d) through one layer -> (its output, its balance
+    loss)."""
+    pre = f"layers.{kind}."
+    h = hr + _mla(_rms(hr, lw[pre + "norm1.g"]), lw, pre, z, es)
+    x = _rms(h, lw[pre + "norm2.g"])
+    if kind == "dense":
+        return h + _gated(x, lw[pre + "wg"], lw[pre + "wi"],
+                          lw[pre + "wo_mlp"], es), jnp.zeros((), jnp.float32)
+    o, aux = _moe(x, lw, z, es)
+    return h + o, aux
 
-        def body(carry, lw, kind=kind, pre=pre):
-            h, aux = carry
-            h = h + _rows(lambda hr: _mla(_rms(hr, lw[pre + "norm1.g"]), lw,
-                                          pre, z, es), h)
-            x = _rms(h, lw[pre + "norm2.g"])
-            if kind == "dense":
-                o = _rows(lambda xr: _gated(xr, lw[pre + "wg"],
-                                            lw[pre + "wi"],
-                                            lw[pre + "wo_mlp"], es), x)
-            else:
-                o, a = _rows(lambda xr: _moe(xr, lw, z, es), x)
-                aux = aux + a
-            return (h + o, aux), None
-        carry, _ = jax.lax.scan(jax.checkpoint(body), carry,
-                                {k: v for k, v in w.items()
-                                 if k.startswith(pre)})
-    h, aux = carry
-    return _rms(h, w["final_norm.g"]), aux
+
+def _stack(h, w, kind, z, es):
+    """The ``layers.<kind>.*`` stack over (B, T, d) under a scan, a
+    sequence at a time in each layer -> (output, each sequence's balance
+    loss summed over the stack (B,)). The backward keeps each layer's
+    input: the sequences are recomputed from it."""
+    pre = f"layers.{kind}."
+
+    def body(h, lw):
+        return _rows(lambda hr: _layer(hr, lw, kind, z, es), h)
+    h, aux = jax.lax.scan(body, h, {k: v for k, v in w.items()
+                                    if k.startswith(pre)})
+    return h, jnp.sum(aux, 0)
+
+
+def hidden(w, tokens, z, es):
+    """tokens (B, T) -> (hidden states before the final norm (B, T, d),
+    each sequence's balance loss summed over the MoE layers (B,)). Only
+    the layers' inputs are kept for the backward: the embedding and the
+    dense layers are recomputed from the tokens."""
+    h = jax.checkpoint(lambda w, t: _stack(w["embed"][t], w, "dense", z,
+                                           es)[0])(w, tokens)
+    return _stack(h, w, "moe", z, es)
 
 
 def loss_mean(w, tokens, labels, c: dict, mode: str = "fp32"):
     """Mean next-token cross-entropy over the batch plus the mean over
-    its sequences of the balance loss; the head one sequence at a
-    time."""
+    its sequences of the balance loss; the final norm and the head one
+    sequence at a time."""
     z = dims(c)
     es = einsum(mode)
     h, aux = hidden(w, tokens, z, es)
 
     def row(hr, lr):
-        logits = es("td,dv->tv", hr, w["lm_head"])
+        logits = es("td,dv->tv", _rms(hr, w["final_norm.g"]), w["lm_head"])
         lse = jax.nn.logsumexp(logits, -1)
         return jnp.sum(lse - jnp.take_along_axis(logits, lr[:, None],
                                                  -1)[:, 0])

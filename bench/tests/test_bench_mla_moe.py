@@ -1,13 +1,20 @@
 """The DeepSeek-V2 family (``bench/families/mla_moe.py``) and the MoE
 cell's readers: the configuration keeps the published widths, the FLOP
 count against a hand count, the program's configuration from the file,
-and the new per-layer readers on a synthetic trace."""
+the new per-layer readers on a synthetic trace, and the MoE cell's run at
+a small size on the CPU, sound and with a fault planted underneath."""
+import contextlib
+import dataclasses
 import sys
 
+import jax
 import pytest
 
 from bench import common as C
+from bench import faults
+from bench import run as R
 from bench import scopes as SC
+from bench.tests import tiny
 
 CELL = "deepseek-v2-lite-16b.train-moe-8k"
 FAM = C.load_family("mla_moe")
@@ -120,3 +127,43 @@ def test_experts_roofline_reads_nothing_without_kernel_calls(monkeypatch):
     ctx = {"trace": {"ops": {"fusion.3 bf16[8]": 1.0}, "window_s": 1.0},
            "tokens": 1, "peak": {}}
     assert C.load_reader("experts_kernel_roofline.train")(ctx) is None
+
+
+# Limits at this small size, as bench/tests/test_bench_faults.py's; the
+# cell's own limits are set from chip runs at its size.
+TRAIN_LIMITS = {"loss_gap": 1e-3, "grad_gap": 8e-3, "change_gap": 4e-3}
+
+
+@contextlib.contextmanager
+def renormalised_gates():
+    """The program with the top-k gates renormalised to sum to 1 (the
+    softmax of the top-k logits), where the reference keeps DeepSeek-V2's
+    unrenormalised softmax probabilities: the comparison has to see the
+    gate's form."""
+    from repro.layers import moe
+    gates = moe._topk_gates
+
+    def renormalised(logits, mc):
+        return gates(logits, dataclasses.replace(mc, norm_topk=True))
+    with faults._patched(moe, "_topk_gates", renormalised):
+        yield
+
+
+PLANTED = {None: contextlib.nullcontext,
+           "half_batch": faults.half_batch,
+           "state_unchanged": faults.state_unchanged,
+           "renormalised_gates": renormalised_gates}
+
+
+@pytest.mark.parametrize("fault", list(PLANTED))
+def test_moe_cell_is_correct_only_without_a_fault(fault):
+    """The MoE cell's driver, options and microbatches at the size of the
+    family's tiny chip-share variant: a sound run is correct, and the run
+    with half the batch, with the state unchanged or with renormalised
+    gates is not."""
+    cell = tiny.cell(CELL, tiny.config("mla-yarn-moe-held-8-of-16"),
+                     TRAIN_LIMITS)
+    with PLANTED[fault]():
+        out, checks = R.execute(cell, 2**31 + 3, 0.3, False,
+                                jax.devices()[:1])
+    assert out["correct"] == (fault is None), checks

@@ -343,7 +343,18 @@ def tp_grouped_matmul(x, w, sizes, axes: M.MeshAxes,
     The weights are gathered over z; the product is all-reduced over
     ``in_shard``; the backward all-reduces dX over ``out_shard`` and
     reduce-scatters dW over z. Rows past ``sum(sizes)`` are unspecified,
-    forward and backward."""
+    forward and backward. The overlap settings that would ring these
+    collectives (``overlap.batched_matmul`` over z, ``overlap.all_reduce``
+    over x) are refused, not ignored: this path has no ring schedule."""
+    rings = {"batched_matmul": _zring(axes, axes.overlap.batched_matmul),
+             "all_reduce": _arring(axes, _logical(axes, in_shard))
+             or _arring(axes, _logical(axes, out_shard))}
+    on = sorted(k for k, r in rings.items() if r is not None)
+    if on:
+        raise NotImplementedError(
+            f"overlap.{'/'.join(on)}: the dropless MoE dispatch "
+            "(g_expert 1) runs its grouped products on the blocking "
+            "schedule only")
     wf = M.all_gather(w, axes.z, dim=2)
     return M.psum(GM.grouped_matmul(x, wf, sizes),
                   _logical(axes, in_shard))

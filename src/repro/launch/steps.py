@@ -236,6 +236,13 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, axes: M.MeshAxes,
             loss = metrics = grads = None
             for i in range(n):
                 sub = jax.tree.map(lambda x: x[i], mb)
+                if grads is not None and n > 2:
+                    # the earlier microbatches' gradients are summed before
+                    # this one's forward starts: else XLA may run
+                    # microbatches side by side, and memory grows with n at
+                    # one microbatch shape. Two keep the free schedule,
+                    # which holds one bf16 gradient in place of the sum.
+                    grads, sub = jax.lax.optimization_barrier((grads, sub))
                 (li, mi), gi = vg(params, sub)
                 with trace.layer("update"):
                     loss = li if loss is None else loss + li

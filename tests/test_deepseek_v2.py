@@ -196,6 +196,22 @@ def test_no_drops_when_every_token_picks_the_same_experts():
     assert max_mean == pytest.approx(8 / 6)
 
 
+def test_grouped_products_refuse_the_ring_overlap():
+    """The dropless path's grouped products run the blocking schedule
+    only: an overlap setting that would ring their z or x collectives is
+    refused when the layer is traced, not silently ignored."""
+    from repro.core import parallel as PP
+    from repro.core.overlap import OverlapConfig
+    axes = LM.bind_4d(LM.make_smoke_mesh((1, 2, 1, 2)))
+    x, w = jnp.zeros((8, 16)), jnp.zeros((2, 16, 32))
+    sizes = jnp.array([4, 4], jnp.int32)
+    for ov in (OverlapConfig(batched_matmul=True),
+               OverlapConfig(all_reduce=True)):
+        with pytest.raises(NotImplementedError, match="overlap"):
+            PP.tp_grouped_matmul(x, w, sizes, axes.with_overlap(ov), "x",
+                                 None)
+
+
 def test_train_step_reports_the_counters():
     """The counters of every MoE layer ride in the train step's metrics:
     the held share near 8/64 for a chip's share, nothing dropped."""

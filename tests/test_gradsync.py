@@ -567,11 +567,11 @@ def test_dp_bucket_count_is_latency_knob():
 # --------------------------------------------------------------------- #
 
 def test_overdecompose_fp32_accumulation_parity():
-    """overdecompose=2 must track the single-batch (=1) trajectory to
-    fp32-reassociation precision now that microbatch grads accumulate in
-    fp32."""
+    """overdecompose=2 and 4 must track the single-batch (=1) trajectory
+    to fp32-reassociation precision now that microbatch grads accumulate
+    in fp32."""
     losses = {}
-    for od in (1, 2):
+    for od in (1, 2, 4):
         _, _, _, _, fn, params, state, batch, _ = _model_setup(
             SHAPE_2X2, GradSyncConfig(), overdecompose=od)
         p, s = params, state
@@ -579,6 +579,24 @@ def test_overdecompose_fp32_accumulation_parity():
             p, s, m = fn(p, s, batch)
         losses[od] = float(m["loss"])
     assert abs(losses[1] - losses[2]) < 1e-5, losses
+    assert abs(losses[1] - losses[4]) < 1e-5, losses
+
+
+def _barriers(od):
+    _, _, _, _, fn, params, state, batch, _ = _model_setup(
+        SHAPE_2X2, GradSyncConfig(), overdecompose=od)
+    return fn.lower(params, state, batch).as_text().count(
+        "optimization_barrier")
+
+
+@pytest.mark.parametrize("od,ordered", [(2, 0), (4, 3)])
+def test_overdecompose_orders_the_microbatches(od, ordered):
+    """Past two microbatches, each one's forward starts after the fp32
+    sum of the earlier ones' gradients (one barrier per later
+    microbatch, beside the barriers that each microbatch's remat has),
+    so that memory does not grow with their number; two keep the free
+    schedule."""
+    assert _barriers(od) - od * _barriers(1) == ordered
 
 
 def test_split_batch_error_is_clear():
