@@ -101,25 +101,24 @@ def ring_place(block, name: AxisRef, mm: Callable, *, gdim: int,
     m = block.shape[gdim] // chunks
     curs = [lax.slice_in_dim(block, q * m, (q + 1) * m, axis=gdim)
             for q in range(chunks)]
-    out = None
-    piece_w = 0
+    ys: List[List] = []                         # ys[s][q]: hop s, piece q
     for s in range(p):
         with trace.scope("ring_ag", name, f"hop{s}"):
-            j = (idx - s) % p
             nxt: List = []
+            ys.append([])
             for q, cur in enumerate(curs):
                 with trace.scope("gemm", None, f"chunk{q}"):
-                    y = mm(cur)
-                if out is None:
-                    piece_w = y.shape[-1]
-                    out = jnp.zeros(y.shape[:-1] + (p * chunks * piece_w,),
-                                    y.dtype)
-                out = lax.dynamic_update_slice_in_dim(
-                    out, y, (j * chunks + q) * piece_w, axis=-1)
+                    ys[s].append(mm(cur))
                 if s < p - 1:
                     nxt.append(lax.ppermute(cur, axn, perm))
             curs = nxt
-    return out
+    # slot j holds rank j's block, which reached this rank at hop
+    # (idx - j) mod p: a select on that scalar, which fuses with the
+    # consumer, where a zero-filled output updated in place would not
+    with trace.scope("ring_ag", name, "place"):
+        return jnp.concatenate(
+            [lax.select_n((idx - j) % p, *(y[q] for y in ys))
+             for j in range(p) for q in range(chunks)], axis=-1)
 
 
 def ring_accumulate(lhs, block, name: AxisRef, mm: Callable, *, gdim: int,

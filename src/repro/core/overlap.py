@@ -53,8 +53,9 @@ class OverlapConfig:
 
     matmul / batched_matmul / tied_logits: use the ring-decomposed
     (overlapped) z-axis schedule inside ``tp_matmul`` /
-    ``tp_batched_matmul`` / ``tied_lm_logits``. Off (default) keeps the
-    blocking all-gather / reduce-scatter schedule.
+    ``tp_batched_matmul`` / ``tied_lm_logits``. Off (the field default)
+    keeps the blocking all-gather / reduce-scatter schedule; training's
+    ``TrainOptions`` turns ``matmul`` on.
 
     all_reduce: ring-decompose the x/y *activation* all-reduces of the
     same three primitives (fwd partial-output reduce, bwd dX reduce, tied

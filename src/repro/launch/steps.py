@@ -140,8 +140,11 @@ class TrainOptions:
     unroll_layers: bool = False  # exact HLO costs for the dry-run
     mtp_weight: float = 0.0      # DeepSeek MTP loss weight (0 = off)
     # ring-decomposed collective matmuls + weight-gather caching
-    # (core/overlap.py; rides down to the layers via axes.with_overlap)
-    overlap: OverlapConfig = OverlapConfig()
+    # (core/overlap.py; rides down to the layers via axes.with_overlap).
+    # tp_matmul's z weight gathers and fp32 gradient reduce-scatters ring
+    # under its GEMMs; with z unmapped the ring is inert, and the step
+    # compiles to the blocking schedule that OverlapConfig() selects.
+    overlap: OverlapConfig = OverlapConfig(matmul=True)
     # data-parallel gradient sync: bucketed ring reduce-scatter streamed
     # through the overdecompose loop, optionally with ZeRO-1 data-axis
     # sharding of the AdamW state (core/gradsync.py)

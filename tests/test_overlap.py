@@ -9,6 +9,8 @@ all-gather / reduce-scatter — and, with ``all_reduce`` on, the x/y
 activation all-reduces — used to be. Shapes scale down automatically on
 4-device CI hosts (conftest.N_DEVICES).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import N_DEVICES, fitting_shapes
+from test_layer_scopes import _program
 from repro.core import collective_matmul as CMM
 from repro.core import comm_model as CM
 from repro.core import mesh as M
@@ -406,6 +409,65 @@ def test_overlap_hlo_uses_collective_permute():
     est_r = RL.step_time_estimate(1e9, ring.bytes_by_kind)
     assert est_r.exposed_comm < est_b.exposed_comm
     assert est_r.hidden_comm > 0.0
+
+
+@pytest.mark.parametrize("shape", [(1, 1, 2, 2), (1, 1, 2, 1),
+                                   (1, 1, 1, 1)], ids=str)
+def test_train_step_default_rings_z_where_mapped(shape):
+    """Training's default ``TrainOptions`` rings ``tp_matmul``'s z
+    collectives wherever z is mapped: one step's loss, gradients (AdamW's
+    first moment, unclipped) and updated parameters match the blocking
+    schedule (``OverlapConfig()``) within the ring-vs-blocking
+    tolerances; with z unmapped the ring is inert and the compiled step
+    is the blocking one, metadata aside."""
+    from repro.configs import get_config
+    from repro.core.partition import spec_tree_to_pspecs
+    from repro.launch import steps as ST
+    from repro.optim import adamw as OPT
+
+    mesh = LM.make_smoke_mesh(shape)
+    axes = LM.bind_4d(mesh)
+    cfg = get_config("gpt-paper-20b").reduced()
+    params, specs = ST.init_model(cfg, axes, jax.random.PRNGKey(0),
+                                  dtype=jnp.float32)
+    params = ST.device_put_tree(mesh, params, spec_tree_to_pspecs(specs))
+    state = OPT.init_state(params)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+    opt = OPT.AdamWConfig(lr=1e-3, warmup_steps=1, grad_clip=0.0,
+                          weight_decay=0.0)
+
+    def step(opts):
+        fn, _, _ = ST.make_train_step(cfg, mesh, axes, opt, opts)
+        return fn.lower(params, state, batch).compile()
+
+    default = ST.TrainOptions(dtype=jnp.float32)
+    assert default.overlap == OverlapConfig(matmul=True)
+    ring = step(default)
+    blocking = step(dataclasses.replace(default, overlap=OverlapConfig()))
+    rt, bt = ring.as_text(), blocking.as_text()
+    if shape[3] == 1:
+        assert _program(rt) == _program(bt)
+        return
+    counts = RL.parse_collectives(rt).counts
+    assert counts.get("collective-permute", 0) > 0, counts
+    # the step donates its state: each schedule steps from its own copy
+    (pr, sr, mr), (pb, sb, mb) = (
+        fn(*jax.tree.map(jnp.copy, (params, state)), batch)
+        for fn in (ring, blocking))
+    np.testing.assert_allclose(float(mr["loss"]), float(mb["loss"]),
+                               rtol=2e-5)
+    treedef = jax.tree.structure(params)
+
+    def grads(s):
+        return [st["m"] / (1 - opt.b1)
+                for st in treedef.flatten_up_to(s["opt"])]
+    for part, r, b in (("grad", grads(sr), grads(sb)),
+                       ("param", jax.tree.leaves(pr), jax.tree.leaves(pb))):
+        for a, c in zip(r, b, strict=True):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=2e-5, atol=1e-5, err_msg=part)
 
 
 def test_ar_overlap_hlo_replaces_all_reduces():

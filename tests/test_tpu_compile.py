@@ -224,3 +224,34 @@ def test_full_depth_train_step_shards_over_four_chips(topo):
     assert ma.argument_size_in_bytes <= state_bytes / 3, (
         ma.argument_size_in_bytes, state_bytes)
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BUDGET
+
+
+def test_four_chip_cell_rings_its_weight_collectives(topo):
+    """The benchmark's four-chip cell (gpt-paper-20b-2l at (1,1,2,2),
+    8 x 2048, the training defaults) compiles for the v5e:2x2; the z
+    weight all-gathers and fp32 gradient reduce-scatters of the layers
+    are left nowhere, synchronous or async: each became ring hops, async
+    ``collective-permute-start``/``-done`` pairs in the layers and the
+    head. Only the embedding's gather and reduce-scatter (``vocab``)
+    stay collectives of their own."""
+    name = "gpt-paper-20b.train-4d"
+    cell = BC.load_cell(name)
+    mesh, axes = LM.MeshLifecycle(*cell.spec["mesh"],
+                                  devices=topo.devices).build()
+    B, T = cell.traffic["global_batch"], cell.traffic["seq_len"]
+    compiled, _ = _compile_train_step(
+        BP.arch(cell.config), mesh, axes, batch=B, seq=T,
+        overdecompose=ST.TrainOptions().overdecompose)
+    ma = compiled.memory_analysis()
+    print(f"{name}: arguments {ma.argument_size_in_bytes / GiB:.2f} GiB + "
+          f"temporaries {ma.temp_size_in_bytes / GiB:.2f} GiB")
+    smap = BS.instruction_layers(compiled.as_text(), LAYERS)
+    ops = {}
+    for op, layer in smap.values():
+        ops.setdefault(op, []).append(layer)
+    for op in ("all-gather", "all-gather-start", "reduce-scatter",
+               "reduce-scatter-start"):
+        assert set(ops.get(op, [])) <= {"vocab"}, (op, ops[op])
+    starts = ops.get("collective-permute-start", [])
+    assert sorted(starts) == sorted(ops.get("collective-permute-done", []))
+    assert {"attn", "mlp", "vocab"} <= set(starts), starts
